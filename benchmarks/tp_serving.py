@@ -12,6 +12,12 @@ identical to the single-device engine.
 Each mesh size runs in a child process (XLA's forced host device count is
 fixed at first jax init, so meshes cannot be grown inside one process).
 
+The children are pinned to the CPU backend, on the chip machine too: every
+record names the platform it ran on (`platform`, `device_kind`,
+`device_count`) and every CSV row carries `platform=cpu`. Its timings
+(`tbt_ms_mean`, `wall_s`) are CPU wall-clock, never a chip number; the
+on-chip mesh path is `chip_smoke.py --chips 4`.
+
 Writes `BENCH_tp.json`.
 """
 from __future__ import annotations
@@ -61,7 +67,11 @@ while eng.step():
     peak_reqs = max(peak_reqs, len(eng.active) + len(eng.prefilling))
 wall_s = time.perf_counter() - t0
 s = eng.summary()
+dev = jax.devices()[0]
 print("RESULT" + json.dumps({
+    "platform": dev.platform,
+    "device_kind": dev.device_kind,
+    "device_count": len(jax.devices()),
     "model_axis": m,
     "model_shards": int(s["model_shards"]),
     "per_chip_pool_tokens": per_chip_pool,
@@ -99,15 +109,19 @@ def _run_child(model_axis: int) -> dict:
 
 
 def run_tp_scaling(out_json: str = "BENCH_tp.json", csv_out=None) -> dict:
-    results: dict = {"per_chip_pool_tokens": PER_CHIP_POOL_TOKENS,
+    results: dict = {"platform": "cpu",
+                     "per_chip_pool_tokens": PER_CHIP_POOL_TOKENS,
                      "meshes": []}
     outputs = {}
     for m in MODEL_AXES:
         r = _run_child(m)
+        if r["platform"] != "cpu":
+            raise RuntimeError(f"tp child ran on {r['platform']}, not cpu")
         outputs[m] = r.pop("outputs")
         results["meshes"].append(r)
         if csv_out:
             csv_out(f"tp_model_axis_{m}", r["wall_s"] * 1e6,
+                    f"platform=cpu "
                     f"capacity={r['pool_tokens_capacity']}tok "
                     f"peak={r['admitted_peak_tokens']}tok "
                     f"preempt={r['preemptions']} oom={r['oom_events']}")
@@ -122,6 +136,7 @@ def run_tp_scaling(out_json: str = "BENCH_tp.json", csv_out=None) -> dict:
         json.dump(results, f, indent=2)
     if csv_out:
         csv_out("tp_summary", 0.0,
+                f"platform=cpu "
                 f"peaks={results['admitted_peak_scaling']} "
                 f"identical={results['outputs_identical_to_single_device']} "
                 f"-> {out_json}")

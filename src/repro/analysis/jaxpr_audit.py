@@ -34,10 +34,10 @@ BAD_DTYPES = {"float64", "complex128"}
 
 def _sub_jaxprs(v) -> Iterable:
     """Jaxprs nested inside an eqn param (closed or open, possibly lists)."""
-    import jax
-    if isinstance(v, jax.core.ClosedJaxpr):
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+    if isinstance(v, ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for item in v:

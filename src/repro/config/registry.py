@@ -1,8 +1,12 @@
-"""Architecture registry: --arch <id> -> ModelConfig (full + reduced variants)."""
+"""Architecture registry: --arch <id> -> ModelConfig.
+
+Every arch has a `full` (published) and a `reduced` (CPU test) variant;
+some add a `chip` variant: published widths cut in depth to one TPU
+chip's share of a stated deployment (the config file says which)."""
 from __future__ import annotations
 
 import importlib
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Optional
 
 from repro.config.base import ModelConfig
 
@@ -23,9 +27,15 @@ _ARCH_MODULES = {
 }
 
 
+VARIANTS = ("full", "reduced", "chip")
+
+
 def register(arch_id: str, full: Callable[[], ModelConfig],
-             reduced: Callable[[], ModelConfig]) -> None:
+             reduced: Callable[[], ModelConfig],
+             chip: Optional[Callable[[], ModelConfig]] = None) -> None:
     _REGISTRY[arch_id] = {"full": full, "reduced": reduced}
+    if chip is not None:
+        _REGISTRY[arch_id]["chip"] = chip
 
 
 def _ensure_loaded(arch_id: str) -> None:
@@ -39,7 +49,11 @@ def _ensure_loaded(arch_id: str) -> None:
 
 def get_config(arch_id: str, variant: str = "full") -> ModelConfig:
     _ensure_loaded(arch_id)
-    return _REGISTRY[arch_id][variant]()
+    variants = _REGISTRY[arch_id]
+    if variant not in variants:
+        raise KeyError(f"arch {arch_id!r} has no {variant!r} variant; "
+                       f"it has {sorted(variants)}")
+    return variants[variant]()
 
 
 def list_archs() -> List[str]:

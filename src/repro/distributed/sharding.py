@@ -243,14 +243,8 @@ def maybe_constrain(x, *spec):
     """with_sharding_constraint that no-ops outside a mesh context and drops
     axes the ambient mesh doesn't have — lets model code carry sharding
     hints without binding to a mesh (single-device tests unaffected)."""
-    get_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_mesh is None:
-        # jax < 0.5: fall back to the thread-local physical mesh context
-        env = getattr(jax.interpreters.pxla, "thread_resources", None)
-        m = getattr(env, "env", None) and env.env.physical_mesh
-    else:
-        m = get_mesh()
-    if m is None or getattr(m, "empty", True):
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or m.empty:
         return x
     names = set(m.axis_names)
     clean = []

@@ -1,8 +1,9 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) kernels run under interpret=True; on TPU they lower
-natively. `use_kernel=False` routes to the pure-jnp oracle — the serving and
-training stacks call these entry points so the backend is a config switch.
+On the CPU backend kernels run under interpret=True; on every other backend
+they lower natively, never interpreted. `use_kernel=False` routes to the
+pure-jnp oracle — the serving and training stacks call these entry points
+so the backend is a config switch.
 """
 from __future__ import annotations
 
@@ -19,8 +20,10 @@ from repro.kernels.rmsnorm import rmsnorm_kernel
 from repro.kernels.ssd_scan import ssd_intra_kernel
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """Interpret mode is the CPU backend's stand-in for Mosaic; a TPU
+    always compiles the kernel natively."""
+    return jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("window", "use_kernel", "block_s"))
@@ -29,7 +32,7 @@ def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
     if not use_kernel:
         return ref.decode_attention_ref(q, k, v, q_pos, k_pos, window=window)
     return decode_attention_kernel(q, k, v, q_pos, k_pos, window=window,
-                                   block_s=block_s, interpret=not _on_tpu())
+                                   block_s=block_s, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("window", "use_kernel"))
@@ -41,7 +44,7 @@ def paged_decode_attention(q, k_pool, v_pool, q_pos, kpos_pool, tables, *,
                                               kpos_pool, tables, window=window)
     return paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
                                          tables, window=window,
-                                         interpret=not _on_tpu())
+                                         interpret=_interpret())
 
 
 def paged_decode_attention_tp(q, k_pool, v_pool, q_pos, kpos_pool, tables, *,
@@ -57,7 +60,6 @@ def paged_decode_attention_tp(q, k_pool, v_pool, q_pos, kpos_pool, tables, *,
     to the single-device kernel. Requires KV % model_axis == 0 — head_dim
     sharding would split the softmax contraction and is storage-only
     (callers fall back to the gathered single-device path)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     KV = k_pool.shape[2]
@@ -71,15 +73,15 @@ def paged_decode_attention_tp(q, k_pool, v_pool, q_pos, kpos_pool, tables, *,
                                                   window=window)
         return paged_decode_attention_kernel(q, kp, vp, qp, pp, tb,
                                              window=window,
-                                             interpret=not _on_tpu())
+                                             interpret=_interpret())
 
     head_spec = P(None, "model", None)
     pool_spec = P(None, None, "model", None)
-    return shard_map(
-        local, mesh,
+    return jax.shard_map(
+        local, mesh=mesh,
         in_specs=(head_spec, pool_spec, pool_spec, P(None), P(None, None),
                   P(None, None)),
-        out_specs=head_spec, check_rep=False,
+        out_specs=head_spec, check_vma=False,
     )(q, k_pool, v_pool, q_pos, kpos_pool, tables)
 
 
@@ -93,14 +95,14 @@ def flash_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                                        causal=causal)
     return flash_attention_kernel(q, k, v, q_pos, k_pos, window=window,
                                   causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=not _on_tpu())
+                                  block_k=block_k, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel",))
 def ssd_intra(xdt, cum_a, Br, Cr, *, use_kernel: bool = True):
     if not use_kernel:
         return ref.ssd_intra_ref(xdt, cum_a, Br, Cr)
-    return ssd_intra_kernel(xdt, cum_a, Br, Cr, interpret=not _on_tpu())
+    return ssd_intra_kernel(xdt, cum_a, Br, Cr, interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("use_kernel", "block_w"))
@@ -108,7 +110,7 @@ def rglru_scan(a, bx, h0, *, use_kernel: bool = True, block_w: int = 128):
     if not use_kernel:
         return ref.rglru_scan_ref(a, bx, h0)
     return rglru_scan_kernel(a, bx, h0, block_w=block_w,
-                             interpret=not _on_tpu())
+                             interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "use_kernel",
@@ -118,4 +120,4 @@ def rmsnorm(x, w, *, eps: float = 1e-6, use_kernel: bool = True,
     if not use_kernel:
         return ref.rmsnorm_ref(x, w, eps=eps)
     return rmsnorm_kernel(x, w, eps=eps, block_rows=block_rows,
-                          interpret=not _on_tpu())
+                          interpret=_interpret())

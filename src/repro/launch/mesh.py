@@ -7,15 +7,11 @@ import sys
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: axis_types (Auto) only exists on
-    newer jax; older versions take (shape, axis_names) alone."""
+    """jax.make_mesh with every axis Auto (GSPMD-propagated shardings)."""
     import jax
 
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(at.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,5 +1,6 @@
 """Serving CLI: run the continuous-batching engine on any --arch (reduced
-variants on CPU; the same engine is the production template for TPU).
+variants on CPU; `--variant chip` serves published widths on one TPU chip,
+as `chip_smoke.py` at the repo root does through `build_engine`).
 
     PYTHONPATH=src python -m repro.launch.serve --arch granite-3-8b \
         --policy combined --sla-ms 200 --requests 20
@@ -16,7 +17,8 @@ from __future__ import annotations
 import argparse
 
 from repro.config.base import ServeConfig
-from repro.config.registry import get_config, list_archs
+from repro.config.registry import VARIANTS, get_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import ensure_cpu_devices
 from repro.serving.cost_model import PROFILES
 
@@ -44,10 +46,13 @@ def parse_mesh(spec: str):
     return shape
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-8b", choices=list_archs())
-    ap.add_argument("--variant", default="reduced", choices=["reduced", "full"])
+    ap.add_argument("--variant", default="reduced", choices=list(VARIANTS),
+                    help="'reduced' (CPU test widths), 'full' (published "
+                         "config) or 'chip' (published widths, depth cut "
+                         "to one chip's share; not every arch has one)")
     ap.add_argument("--policy", default="memory",
                     choices=["static", "memory", "sla", "combined"])
     ap.add_argument("--sla-ms", type=float, default=0.0)
@@ -138,14 +143,21 @@ def main():
                          "'model' (TP) axis and --pool-tokens becomes a "
                          "PER-CHIP budget (DESIGN §12). On CPU, forced "
                          "host devices are provisioned automatically.")
-    args = ap.parse_args()
+    return ap
 
+
+def build_engine(args):
+    """The serving engine `main` runs, from parsed flags: model config,
+    parameters created on the device(s) by one jitted init (bf16 for every
+    non-reduced variant; under `--mesh` each shard is created in place),
+    and the Engine. Returns (engine, model config)."""
     if args.mesh:
         n = 1
         for s in args.mesh:
             n *= s
         ensure_cpu_devices(n)
 
+    enable_compile_cache()
     import jax
 
     if args.mesh and len(jax.devices()) < n:
@@ -156,7 +168,6 @@ def main():
             f"below {n} (ensure_cpu_devices won't override it) — unset it "
             f"or raise it to {n}.")
     import jax.numpy as jnp
-    import numpy as np
 
     from repro.models.model import build_model, default_enc_len
     from repro.serving.cost_model import CostModel
@@ -165,7 +176,15 @@ def main():
     cfg = get_config(args.arch, args.variant)
     model = build_model(cfg, dtype=jnp.float32 if args.variant == "reduced"
                         else jnp.bfloat16)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    mesh = None
+    shardings = None
+    if args.mesh:
+        from repro.distributed.sharding import serve_param_shardings
+        from repro.launch.mesh import make_serving_mesh
+        mesh = make_serving_mesh(args.mesh)
+        shardings = serve_param_shardings(model.init_shapes(), cfg, mesh)
+    params = jax.jit(model.init, out_shardings=shardings)(
+        jax.random.PRNGKey(args.seed))
     buckets = args.batch_buckets or \
         tuple(2 ** i for i in range(0, args.b_max.bit_length()))
     serve = ServeConfig(policy=args.policy,
@@ -195,9 +214,18 @@ def main():
     eng = Engine(model, params, serve, max_context=args.max_context,
                  buckets=buckets,
                  prefill_chunk=16, enc_len=enc_len,
-                 cost=CostModel(cfg, PROFILES[args.profile]))
+                 cost=CostModel(cfg, PROFILES[args.profile]), mesh=mesh)
+    return eng, cfg
+
+
+def main():
+    args = build_parser().parse_args()
+    eng, cfg = build_engine(args)
+    import jax.numpy as jnp
+    import numpy as np
 
     rng = np.random.RandomState(args.seed)
+    enc_len = eng.enc_len
 
     def mk_extras():
         if not enc_len:

@@ -35,9 +35,11 @@ def use_pallas() -> bool:
 
 
 def dense_init(key, shape, scale: Optional[float] = None, dtype=jnp.float32):
+    """Scaled normal draw made in `dtype` itself: a bf16 model never holds
+    an fp32 copy of a weight, even transiently."""
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     scale = (1.0 / math.sqrt(fan_in)) if scale is None else scale
-    return (jax.random.normal(key, shape) * scale).astype(dtype)
+    return jax.random.normal(key, shape, dtype) * scale
 
 
 def rms_norm(x, w, eps: float = 1e-6):

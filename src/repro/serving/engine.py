@@ -344,11 +344,10 @@ class Engine:
 
     def _shard_state(self):
         """Place params on the mesh: TP over "model" (§5 rules, data axes
-        replicated). Params arrive caller-materialized, so this is a
-        reshard (`device_put`); production callers serving models that
-        don't fit one chip should init params under
-        `serve_param_shardings` to begin with (the cache never needs this
-        — `_init_cache_on_mesh` allocates it sharded)."""
+        replicated). Params already created under `serve_param_shardings`
+        (as `launch/serve.py` creates them, so a model that does not fit
+        one chip never lands on one) pass through unchanged; others are
+        resharded (`device_put`)."""
         from repro.distributed.sharding import serve_param_shardings
         self.params = jax.device_put(
             self.params,

@@ -1,4 +1,6 @@
 """The 10 assigned architecture configs must match the assignment exactly."""
+import dataclasses
+
 import pytest
 
 from repro.config.base import ArchFamily
@@ -73,3 +75,19 @@ def test_kv_bytes_per_token():
     rg = get_config("recurrentgemma-9b")
     n_att = sum(1 for k in rg.layer_kinds() if k == "attention")
     assert rg.kv_bytes_per_token() == 2 * n_att * 1 * 256 * 2
+
+
+def test_granite_chip_variant_keeps_published_widths():
+    """`chip` cuts only depth, and its bf16 weights fit in half of one
+    16 GiB chip, leaving the other half for the KV pool."""
+    full = get_config("granite-3-8b", "full")
+    chip = get_config("granite-3-8b", "chip")
+    assert chip.num_layers == 20 < full.num_layers
+    assert dataclasses.replace(chip, name=full.name,
+                               num_layers=full.num_layers) == full
+    assert chip.param_count() * 2 <= 16 * 2**30 // 2
+
+
+def test_unknown_variant_names_the_known_ones():
+    with pytest.raises(KeyError, match="reduced"):
+        get_config("mamba2-2.7b", "chip")

@@ -16,8 +16,7 @@ SUBSET = ["granite-3-8b", "mamba2-2.7b", "recurrentgemma-9b",
 
 
 def test_detector_flags_float64():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.ones((2,), jnp.float64))
     fs = _audit_closed(closed, "t", "p.py")

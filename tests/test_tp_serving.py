@@ -148,7 +148,6 @@ SCRIPT = textwrap.dedent("""
 
     # 5) shard_map paged Pallas kernel (interpret): bitwise vs the
     #    single-device kernel, close to the jnp oracle
-    from jax.experimental.shard_map import shard_map
     from repro.kernels.decode_attention import paged_decode_attention_kernel
     from repro.kernels.ops import paged_decode_attention_tp
     from repro.kernels.ref import paged_decode_attention_ref
