@@ -1,0 +1,7 @@
+import sys
+from pathlib import Path
+
+# the program's sources, for runs without PYTHONPATH=src
+SRC = Path(__file__).resolve().parents[2] / "src"
+if str(SRC) not in sys.path:
+    sys.path.insert(0, str(SRC))
