@@ -39,6 +39,18 @@ from repro.serving.request import Request, RequestState
 from repro.serving.sampling import sample
 
 
+# host spans on the profiler's clock (DESIGN §16), one per phase of
+# step(). jax.profiler annotations: no-ops unless a trace is running, and
+# never inside a jitted function (they would fire at trace time only)
+SPANS = ("engine.step", "engine.schedule", "engine.admit", "engine.preempt",
+         "engine.prefill", "engine.decode", "engine.release",
+         "engine.retire.fence", "engine.retire.readback",
+         "engine.retire.stamp")
+(SPAN_STEP, SPAN_SCHEDULE, SPAN_ADMIT, SPAN_PREEMPT, SPAN_PREFILL,
+ SPAN_DECODE, SPAN_RELEASE, SPAN_FENCE, SPAN_READBACK, SPAN_STAMP) = SPANS
+_span = jax.profiler.TraceAnnotation
+
+
 def _batch_axis(name: str) -> int:
     return 0 if name == "pos" else 1
 
@@ -103,8 +115,9 @@ class _StepRec:
     #: (request, output index, life generation, "d"|"f", payload row)
     patches: List[Tuple[Request, int, int, str, int]] = \
         dataclasses.field(default_factory=list)
-    #: (request, feed on_first_token, queue_s, prefill_start) TTFT stamps
-    firsts: List[Tuple[Request, bool, float, float]] = \
+    #: (request, life generation, feed on_first_token, queue_s,
+    #: prefill_start) TTFT stamps
+    firsts: List[Tuple[Request, int, bool, float, float]] = \
         dataclasses.field(default_factory=list)
     #: (request, output length) completion stamps, finish order preserved
     completions: List[Tuple[Request, int]] = \
@@ -238,6 +251,10 @@ class Engine:
         # admission drains this queue before `waiting`
         self.swapped: List[Request] = []
         self.now0 = time.perf_counter()
+        # summary()'s serving window: first submitted arrival to the last
+        # retirement, so warm-up and compiles before it are not counted
+        self._first_arrival: Optional[float] = None
+        self._last_retire: Optional[float] = None
         self._next_rid = 0
         self.total_decoded = 0
         self.total_finished = 0
@@ -451,9 +468,12 @@ class Engine:
         """Clear the pos-pool rows of freed blocks so a future tenant never
         sees the previous request's stale positions (DESIGN §9)."""
         if self.paged and freed and "pos" in self.cache:
-            out = dict(self.cache)
-            out["pos"] = out["pos"].at[jnp.asarray(freed, jnp.int32)].set(-1)
-            self.cache = out
+            # eager: one small program per freed-block count
+            with _span(SPAN_RELEASE, blocks=len(freed)):
+                out = dict(self.cache)
+                out["pos"] = out["pos"].at[
+                    jnp.asarray(freed, jnp.int32)].set(-1)
+                self.cache = out
 
     def _drain_released(self):
         """Clear pos rows of blocks the allocator evicted from the prefix
@@ -507,6 +527,8 @@ class Engine:
         r = Request(rid=self._next_rid, arrival_time=t,
                     prompt_tokens=list(prompt_tokens), max_new_tokens=mx)
         self._next_rid += 1
+        if self._first_arrival is None:
+            self._first_arrival = t
         r.extras = extras
         self.waiting.append(r)
         self.tel.on_arrival(t, r.prompt_len)
@@ -573,7 +595,15 @@ class Engine:
         then retire the oldest in-flight interval(s) until at most
         `overlap_depth` device steps remain in flight. Depth 0 retires N
         before returning — the synchronous loop, interval for interval.
+
+        Each phase runs inside its host span (DESIGN §16), the whole call
+        inside `engine.step` numbered by interval.
         """
+        with jax.profiler.StepTraceAnnotation(
+                SPAN_STEP, step_num=len(self.step_host_trace)):
+            return self._step()
+
+    def _step(self) -> bool:
         if not self.waiting and not self.active and not self.prefilling \
                 and not self.swapped:
             # pipeline drain: retirement only patches token values,
@@ -584,26 +614,70 @@ class Engine:
                 self._retire_step()
             return False
         t0 = time.perf_counter()
-        tel = self.tel.snapshot(
-            now=self._now(),
-            n_prefill=len(self.waiting) + len(self.prefilling),
-            n_decode=len(self.active), free_tokens=self.blocks.free_tokens,
-            logical_used_tokens=self.blocks.logical_used_tokens,
-            physical_used_tokens=self.blocks.physical_used_tokens,
-            swapped_tokens=self.blocks.swapped_tokens)
-        decision = self.policy.step(tel)
-        # sim-mirrored admission (DESIGN §7): bucketize the controller's cap
-        # to the compiled batch buckets and apply the shared
-        # BlockManager.admission_verdict (vLLM 1% watermark + unservable
-        # rejection), counting watermark refusals as oom_events.
-        # bucketize rounds UP to the floor bucket when b_t is below the
-        # smallest compiled one — admitted rows must still respect the
-        # controller's decision (the graph pads, admission must not)
-        cap = bucketize(decision.max_batch, self.serve.batch_buckets) \
-            if self.serve.batch_buckets else decision.max_batch
-        cap = min(cap, decision.max_batch, self.max_slots)
+        with _span(SPAN_SCHEDULE):
+            tel = self.tel.snapshot(
+                now=self._now(),
+                n_prefill=len(self.waiting) + len(self.prefilling),
+                n_decode=len(self.active),
+                free_tokens=self.blocks.free_tokens,
+                logical_used_tokens=self.blocks.logical_used_tokens,
+                physical_used_tokens=self.blocks.physical_used_tokens,
+                swapped_tokens=self.blocks.swapped_tokens)
+            decision = self.policy.step(tel)
+            # sim-mirrored admission (DESIGN §7): bucketize the
+            # controller's cap to the compiled batch buckets and apply the
+            # shared BlockManager.admission_verdict (vLLM 1% watermark +
+            # unservable rejection), counting watermark refusals as
+            # oom_events. bucketize rounds UP to the floor bucket when b_t
+            # is below the smallest compiled one — admitted rows must
+            # still respect the controller's decision (the graph pads,
+            # admission must not)
+            cap = bucketize(decision.max_batch, self.serve.batch_buckets) \
+                if self.serve.batch_buckets else decision.max_batch
+            cap = min(cap, decision.max_batch, self.max_slots)
         rec = _StepRec()
+        with _span(SPAN_ADMIT, waiting=len(self.waiting)):
+            self._admit(cap, rec)
+        with _span(SPAN_PREEMPT):
+            self._preempt_if_needed()
+        if self.serve.chunked_prefill:
+            # PD fusion: one fused interval = a prefill chunk (within the
+            # controller's token budget) + the decode batch; TBT accounts
+            # for both (the paper's adaptive-chunk-size scenario)
+            budget = decision.chunk_budget \
+                or self.serve.chunk_budget_tokens
+            if budget <= 0 and self.prefilling and not self.active:
+                # nothing decoding and no token budget: the engine would
+                # spin no-op intervals forever — make minimum progress on
+                # one full chunk instead of livelocking
+                budget = self.prefill_chunk
+            if self.prefilling:
+                with _span(SPAN_PREFILL, budget=budget, lanes_busy=min(
+                        self.n_lanes, len(self.prefilling))):
+                    self._advance_prefill(budget, rec)
+            if self.active:
+                self._decode_once(rec)
+        elif self.active:
+            self._decode_once(rec)
+        if rec.dispatched:
+            self._inflight.append(rec)
+        # retire down to the pipeline depth: the fence wait is the
+        # interval's device time; everything else this call did is host
+        # work the in-flight step(s) just hid
+        device_s = 0.0
+        while len(self._inflight) > self.overlap_depth:
+            device_s += self._retire_step()
+        host_s = (time.perf_counter() - t0) - device_s
+        self.step_host_trace.append(host_s)
+        self.step_device_trace.append(device_s)
+        # fed live, not lagged: the split is produced by retirement
+        # itself, not by the interval being scheduled (DESIGN §14)
+        self.tel.on_interval(host_s, device_s)
+        return True
 
+    def _admit(self, cap: int, rec: _StepRec) -> None:
+        """Swap-in drain, then admission from `waiting`, up to `cap`
+        requests holding a slot or lane."""
         # swap-in drain (DESIGN §11): offloaded requests re-enter BEFORE
         # any new admission — they resume decode without re-prefill, and
         # while any remain, `waiting` is held back so fresh arrivals can
@@ -614,7 +688,6 @@ class Engine:
                 self.oom_events += 1
                 break
 
-        # admission
         while self.waiting and not self.swapped \
                 and len(self.active) + len(self.prefilling) < cap:
             r = self.waiting[0]
@@ -651,6 +724,7 @@ class Engine:
                 self.oom_events += 1
                 break
             self.blocks.allocate(r.rid, 0, need)
+            r.admit_time = self._now()
             if self.prefix:
                 self.blocks.note_prefix_query(r.prompt_len, cached)
             r.cached_prefix_len = cached
@@ -663,39 +737,6 @@ class Engine:
             else:
                 self._prefill_request(r, rec)
         self._drain_released()
-
-        self._preempt_if_needed()
-        if self.serve.chunked_prefill:
-            # PD fusion: one fused interval = a prefill chunk (within the
-            # controller's token budget) + the decode batch; TBT accounts
-            # for both (the paper's adaptive-chunk-size scenario)
-            budget = decision.chunk_budget \
-                or self.serve.chunk_budget_tokens
-            if budget <= 0 and self.prefilling and not self.active:
-                # nothing decoding and no token budget: the engine would
-                # spin no-op intervals forever — make minimum progress on
-                # one full chunk instead of livelocking
-                budget = self.prefill_chunk
-            self._advance_prefill(budget, rec)
-            if self.active:
-                self._decode_once(rec)
-        elif self.active:
-            self._decode_once(rec)
-        if rec.dispatched:
-            self._inflight.append(rec)
-        # retire down to the pipeline depth: the fence wait is the
-        # interval's device time; everything else this call did is host
-        # work the in-flight step(s) just hid
-        device_s = 0.0
-        while len(self._inflight) > self.overlap_depth:
-            device_s += self._retire_step()
-        host_s = (time.perf_counter() - t0) - device_s
-        self.step_host_trace.append(host_s)
-        self.step_device_trace.append(device_s)
-        # fed live, not lagged: the split is produced by retirement
-        # itself, not by the interval being scheduled (DESIGN §14)
-        self.tel.on_interval(host_s, device_s)
-        return True
 
     # -- PD fusion internals (DESIGN §6) ---------------------------------------
     def _fill_lanes(self):
@@ -866,7 +907,7 @@ class Engine:
                                 self._gen.get(r.rid, 0), "f", len(flist)))
             flist.append(tok)
             self._pending_tok[r.rid] = tok
-            rec.firsts.append((r, True,
+            rec.firsts.append((r, self._gen.get(r.rid, 0), True,
                                r.prefill_start_time - r.arrival_time,
                                r.prefill_start_time))
             r.output_tokens.append(None)
@@ -893,6 +934,7 @@ class Engine:
             r.slot = slot
             self.cache = cache_clear_row(self.cache, slot)
         r.state = RequestState.PREFILLING
+        r.prefill_start_time = self._now()
         chunk = self.prefill_chunk
         toks = r.prompt_tokens
         extras = getattr(r, "extras", None)
@@ -939,7 +981,7 @@ class Engine:
                             self._gen.get(r.rid, 0), "f", len(flist)))
         flist.append(tok)
         self._pending_tok[r.rid] = tok
-        rec.firsts.append((r, False, 0.0, 0.0))
+        rec.firsts.append((r, self._gen.get(r.rid, 0), False, 0.0, 0.0))
         r.output_tokens.append(None)
         rec.dispatched = True
         rec.payload["probe"] = last_logits
@@ -1056,14 +1098,14 @@ class Engine:
         # against the cleared outputs must not land on the recompute pass
         self._gen[r.rid] = self._gen.get(r.rid, 0) + 1
         r.output_tokens.clear()
-        r.tbt_samples.clear()
         # the recompute pass re-probes the prefix index from scratch — the
         # request's own just-freed blocks are prime cache hits (DESIGN §10)
         r.cached_prefix_len = 0
         # recompute: the next serving pass re-attributes TTFT from scratch
         # (a stale prefill_start_time would count the first life — decode
-        # included — as prefill service)
-        r.prefill_start_time = -1.0
+        # included — as prefill service); the lifecycle stamps describe
+        # the last life only (DESIGN §16)
+        r.admit_time = r.prefill_start_time = r.first_token_time = -1.0
         if self.paged:
             self.active.pop(slot)
         else:
@@ -1077,14 +1119,20 @@ class Engine:
         self.preemptions += 1
 
     def _decode_once(self, rec: _StepRec):
+        n = len(self.active)
+        ge = [b for b in self.buckets if b >= n]
+        bucket = min(ge) if ge else self.max_slots
+        with _span(SPAN_DECODE, rows=n, bucket=bucket):
+            self._decode_batch(rec, n, bucket)
+
+    def _decode_batch(self, rec: _StepRec, n: int, bucket: int):
+        """Dispatch one decode step over the `n` active rows padded to
+        `bucket`, then grow, finish and preempt by the step's lengths."""
         if self.prefix:
             # COW guard on the position each decode writes (DESIGN §10)
             for r in self.active:
                 self._cow_blocks(self.blocks.cow_range(
                     r.rid, r.context_len - 1, r.context_len))
-        n = len(self.active)
-        ge = [b for b in self.buckets if b >= n]
-        bucket = min(ge) if ge else self.max_slots
         # inputs: retired tokens are host ints; un-retired ones (pipeline
         # depth >= 1, or promoted this very interval) are still device
         # scalars and are spliced in without a readback — the VALUES are
@@ -1188,13 +1236,24 @@ class Engine:
         the fence wait in seconds."""
         rec = self._inflight.popleft()
         t0 = time.perf_counter()
-        # THE pipeline fence: the one block the async loop retains
-        jax.block_until_ready(rec.payload)
+        with _span(SPAN_FENCE):
+            # THE pipeline fence: the one block the async loop retains
+            jax.block_until_ready(rec.payload)
         dev_s = time.perf_counter() - t0
-        # everything is ready — one bulk readback, not per-token syncs
-        vals = jax.device_get(rec.payload)
+        with _span(SPAN_READBACK):
+            # everything is ready — one bulk readback, not per-token syncs
+            vals = jax.device_get(rec.payload)
+        with _span(SPAN_STAMP):
+            self._stamp_retired(rec, vals, dev_s)
+        return dev_s
+
+    def _stamp_retired(self, rec: _StepRec, vals: Dict[str, Any],
+                       dev_s: float) -> None:
+        """Patch a retired interval's tokens, stamp its TTFT/finish times,
+        apply its deferred telemetry feeds and commit the shadow epoch."""
         dt_ms = dev_s * 1e3
         now = self._now()
+        self._last_retire = now
         dec = vals.get("dec")
         first = vals.get("first", ())
         for r, idx, gen, kind, k in rec.patches:
@@ -1204,13 +1263,13 @@ class Engine:
             if idx < len(r.output_tokens) and r.output_tokens[idx] is None:
                 r.output_tokens[idx] = int(dec[k] if kind == "d"
                                            else first[k])
-            if kind == "d":
-                # TBT sample = the marginal fence wait this interval cost
-                r.tbt_samples.append(dt_ms)
         if rec.lane_tokens is not None:
             self.tel.on_prefill_interval(rec.lane_tokens, self.n_lanes)
-        for r, feed, queue_s, t_ps in rec.firsts:
-            r.first_token_time = now
+        for r, gen, feed, queue_s, t_ps in rec.firsts:
+            # a life evicted since dispatch gets no stamp: its
+            # recompute pass stamps anew
+            if self._gen.get(r.rid, 0) == gen:
+                r.first_token_time = now
             self.ttft_trace.append(now - r.arrival_time)
             if feed:
                 self.tel.on_first_token(queue_s, now - t_ps)
@@ -1235,11 +1294,14 @@ class Engine:
         # the remaining in-flight interval(s) will record
         self.blocks.shadow_commit()
         self.blocks.shadow_begin()
-        return dev_s
 
     # -- metrics ---------------------------------------------------------------
     def summary(self) -> Dict[str, float]:
-        el = self._now()
+        # rates over the serving window: the first submitted request's
+        # arrival to the last retirement (0 before any retirement)
+        el = 0.0
+        if self._first_arrival is not None and self._last_retire is not None:
+            el = max(self._last_retire - self._first_arrival, 0.0)
         occ = self.tel.lane_occ
         tq, _ = self.tel.ttft_queue.get()
         tp, _ = self.tel.ttft_prefill.get()

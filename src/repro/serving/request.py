@@ -37,10 +37,14 @@ class Request:
     output_tokens: List[int] = dataclasses.field(default_factory=list)
     slot: int = -1                               # engine batch slot
     lane: int = -1                               # PD-fusion prefill lane (DESIGN §6)
+    # lifecycle stamps (DESIGN §16), engine clock; recompute eviction
+    # resets the first three, so they describe the request's last life:
+    # arrival <= admit <= prefill_start <= first_token, and the three
+    # waits between them sum to the engine-side TTFT
+    admit_time: float = -1.0                     # blocks allocated at admission
     prefill_start_time: float = -1.0             # first prefill chunk (TTFT attribution)
     first_token_time: float = -1.0
     finish_time: float = -1.0
-    tbt_samples: List[float] = dataclasses.field(default_factory=list)
     # two-tier swap (DESIGN §11): per-request swap latency accounting
     swap_out_time: float = -1.0                  # pending swap-out timestamp
     swapped_s: float = 0.0                       # total time spent offloaded
