@@ -203,7 +203,7 @@ def kernel_check(eng, seed: int):
     """One paged decode step at full width through the Pallas kernel vs
     the same step through the jnp reference path (`use_pallas` off), from
     one shared prefilled pool, then the kernel alone vs its oracle on
-    layer 0 of that pool. The kernel step's HLO must hold the Mosaic
+    the last layer of that stacked pool. The kernel step's HLO must hold the Mosaic
     custom call; the reference's must not."""
     from unittest import mock
 
@@ -239,16 +239,16 @@ def kernel_check(eng, seed: int):
     check_close("kernel_vs_reference decode logits", got, want,
                 *logits_limits(cfg.num_layers))
 
-    # layer 0's attention over the prefilled pool, query at the last
-    # written position so every written key is visible
+    # the last layer's attention over the prefilled stacked pool, query at
+    # the last written position so every written key is visible
     q = jax.random.normal(jax.random.PRNGKey(seed + 2),
                           (len(lens), cfg.num_heads, cfg.resolved_head_dim),
                           jnp.bfloat16)
-    attn = (q, cache["k"][0], cache["v"][0], jnp.asarray(lens) - 1,
-            cache["pos"], tables)
+    attn = (q, cache["k"], cache["v"], jnp.asarray(lens) - 1,
+            cache["pos"], tables, cfg.num_layers - 1)
     got = ops.paged_decode_attention(*attn, use_kernel=True)
     want = ops.paged_decode_attention(*attn, use_kernel=False)
-    check_close("kernel_vs_reference layer-0 attention", got, want,
+    check_close("kernel_vs_reference last-layer attention", got, want,
                 ATTN_REL_L2, ATTN_MAX_REL)
 
 

@@ -5,9 +5,10 @@ One new query token per sequence against the KV cache, in two layouts:
 * contiguous (`decode_attention_kernel`): k/v are per-slot (B, S, KV, hd)
   rows; grid = (batch, kv_blocks) over the contiguous S axis.
 * paged (`paged_decode_attention_kernel`, DESIGN §9): k/v live in shared
-  (num_blocks, block_size, KV, hd) pools and the kv-block grid axis walks
-  the per-request block table instead of a contiguous row — the table is a
-  scalar-prefetch operand so the BlockSpec index maps can chase it.
+  (layers, num_blocks, block_size, KV, hd) pools and the kv-block grid axis
+  walks the per-request block table instead of a contiguous row — the
+  layer index and the table are scalar-prefetch operands so the BlockSpec
+  index maps can chase them into the stacked pool.
 
 Both accumulate an online softmax in VMEM scratch. Masking is
 position-based (absolute positions per cache slot, -1 = empty), identical
@@ -146,8 +147,8 @@ def decode_attention_kernel(q, k, v, q_pos, k_pos, *, window: int = 0,
     return out.reshape(B, H, hd)
 
 
-def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, window: int):
+def _paged_kernel(lyr_ref, tbl_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
+                  o_ref, m_ref, l_ref, acc_ref, *, window: int):
     b = pl.program_id(0)
     s = pl.program_id(1)
     # unallocated table slots (-1) were clamped to physical block 0 by the
@@ -160,41 +161,45 @@ def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
-                                  tables, *, window: int = 0,
+                                  tables, layer, *, window: int = 0,
                                   interpret: bool = True):
     """Paged flash decode (DESIGN §9).
 
-    q: (B, H, hd); k_pool/v_pool: (NB, bs, KV, hd) shared physical pools;
-    q_pos: (B,); kpos_pool: (NB, bs) absolute positions (-1 = empty);
-    tables: (B, MB) physical block ids per request (-1 = unallocated).
+    q: (B, H, hd); k_pool/v_pool: (L, NB, bs, KV, hd) stacked physical
+    pools, read at `layer` (an int32 scalar, traced inside the model's
+    layer loop); q_pos: (B,); kpos_pool: (NB, bs) absolute positions
+    (-1 = empty), shared by every layer; tables: (B, MB) physical block
+    ids per request (-1 = unallocated).
 
     Grid = (batch, table_slot): the innermost axis walks the block TABLE,
-    not physical memory — `tables` and `q_pos` ride in as scalar-prefetch
-    operands so the k/v/kpos BlockSpec index maps resolve tables[b, s] to
-    the physical block to stream. Each step streams one block of every kv
-    head; `kpos_pool` is viewed as (NB, 1, bs) so its tile spans the
-    array's own unit axis. Returns (B, H, hd)."""
+    not physical memory — `layer`, `tables` and `q_pos` ride in as
+    scalar-prefetch operands so the k/v BlockSpec index maps resolve
+    (layer, tables[b, s]) to the block to stream straight out of the
+    stacked pool, with no per-layer slice of it. Each step streams one
+    block of every kv head; `kpos_pool` is viewed as (NB, 1, bs) so its
+    tile spans the array's own unit axis. Returns (B, H, hd)."""
     B, H, hd = q.shape
-    NB, bs, KV, _ = k_pool.shape
+    _, NB, bs, KV, _ = k_pool.shape
     MB = tables.shape[1]
     G = H // KV
     qr = q.reshape(B, KV, G, hd)
 
-    def pool_map(b, s, t, qp):
-        return (jnp.maximum(t[b, s], 0), 0, 0, 0)
+    def pool_map(b, s, lyr, t, qp):
+        return (lyr[0], jnp.maximum(t[b, s], 0), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, MB),
         in_specs=[
-            pl.BlockSpec((1, KV, G, hd), lambda b, s, t, qp: (b, 0, 0, 0)),  # q
-            pl.BlockSpec((1, bs, KV, hd), pool_map),                      # k
-            pl.BlockSpec((1, bs, KV, hd), pool_map),                      # v
-            pl.BlockSpec((1, 1, bs),
-                         lambda b, s, t, qp: (jnp.maximum(t[b, s], 0), 0, 0)),
+            pl.BlockSpec((1, KV, G, hd),
+                         lambda b, s, lyr, t, qp: (b, 0, 0, 0)),          # q
+            pl.BlockSpec((None, 1, bs, KV, hd), pool_map),                # k
+            pl.BlockSpec((None, 1, bs, KV, hd), pool_map),                # v
+            pl.BlockSpec((1, 1, bs), lambda b, s, lyr, t, qp: (
+                jnp.maximum(t[b, s], 0), 0, 0)),                          # kpos
         ],
         out_specs=pl.BlockSpec((1, KV, G, hd),
-                               lambda b, s, t, qp: (b, 0, 0, 0)),
+                               lambda b, s, lyr, t, qp: (b, 0, 0, 0)),
         scratch_shapes=_scratch(KV, G, hd),
     )
     out = pl.pallas_call(
@@ -202,6 +207,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), q_pos.astype(jnp.int32), qr, k_pool, v_pool,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      q_pos.astype(jnp.int32), qr, k_pool, v_pool,
       kpos_pool.astype(jnp.int32).reshape(NB, 1, bs))
     return out.reshape(B, H, hd)

@@ -10,6 +10,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.decode_attention import (decode_attention_kernel,
@@ -36,53 +37,57 @@ def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "use_kernel"))
-def paged_decode_attention(q, k_pool, v_pool, q_pos, kpos_pool, tables, *,
-                           window: int = 0, use_kernel: bool = True):
-    """Flash decode through the paged KV pools + block tables (DESIGN §9)."""
+def paged_decode_attention(q, k_pool, v_pool, q_pos, kpos_pool, tables, layer,
+                           *, window: int = 0, use_kernel: bool = True):
+    """Flash decode through the stacked paged KV pools + block tables at
+    one layer (DESIGN §9)."""
     if not use_kernel:
         return ref.paged_decode_attention_ref(q, k_pool, v_pool, q_pos,
-                                              kpos_pool, tables, window=window)
+                                              kpos_pool, tables, layer,
+                                              window=window)
     return paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
-                                         tables, window=window,
+                                         tables, layer, window=window,
                                          interpret=_interpret())
 
 
-def paged_decode_attention_tp(q, k_pool, v_pool, q_pos, kpos_pool, tables, *,
-                              mesh, window: int = 0, use_kernel: bool = True):
+def paged_decode_attention_tp(q, k_pool, v_pool, q_pos, kpos_pool, tables,
+                              layer, *, mesh, window: int = 0,
+                              use_kernel: bool = True):
     """Tensor-parallel paged flash decode via shard_map (DESIGN §12).
 
-    The paged kernel's grid is (batch, kv_head, table_slot) — per-kv-head
-    work is fully independent — so TP is a shard_map over the "model"
-    axis: each shard streams its kv-head slice of the K/V pools against
-    its q-head slice (heads are kv-major, so H/m q-heads pair with KV/m
-    kv-heads), with the block table and pos map replicated. No collective
-    runs inside the kernel, which keeps shard outputs bitwise identical
-    to the single-device kernel. Requires KV % model_axis == 0 — head_dim
+    The paged kernel's per-kv-head work is fully independent, so TP is a
+    shard_map over the "model" axis: each shard streams its kv-head slice
+    of the stacked (L, NB, bs, KV, hd) K/V pools against its q-head slice
+    (heads are kv-major, so H/m q-heads pair with KV/m kv-heads), with the
+    layer index, block table and pos map replicated. No collective runs
+    inside the kernel, which keeps shard outputs bitwise identical to the
+    single-device kernel. Requires KV % model_axis == 0 — head_dim
     sharding would split the softmax contraction and is storage-only
     (callers fall back to the gathered single-device path)."""
     from jax.sharding import PartitionSpec as P
 
-    KV = k_pool.shape[2]
+    KV = k_pool.shape[3]
     m = int(mesh.shape["model"])
     if KV % m != 0:
         raise ValueError(f"kv heads {KV} not divisible by model axis {m}")
 
-    def local(q, kp, vp, qp, pp, tb):
+    def local(q, kp, vp, qp, pp, tb, lyr):
         if not use_kernel:
-            return ref.paged_decode_attention_ref(q, kp, vp, qp, pp, tb,
+            return ref.paged_decode_attention_ref(q, kp, vp, qp, pp, tb, lyr,
                                                   window=window)
-        return paged_decode_attention_kernel(q, kp, vp, qp, pp, tb,
+        return paged_decode_attention_kernel(q, kp, vp, qp, pp, tb, lyr,
                                              window=window,
                                              interpret=_interpret())
 
     head_spec = P(None, "model", None)
-    pool_spec = P(None, None, "model", None)
+    pool_spec = P(None, None, None, "model", None)
     return jax.shard_map(
         local, mesh=mesh,
         in_specs=(head_spec, pool_spec, pool_spec, P(None), P(None, None),
-                  P(None, None)),
+                  P(None, None), P()),
         out_specs=head_spec, check_vma=False,
-    )(q, k_pool, v_pool, q_pos, kpos_pool, tables)
+    )(q, k_pool, v_pool, q_pos, kpos_pool, tables,
+      jnp.asarray(layer, jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("window", "causal", "use_kernel",

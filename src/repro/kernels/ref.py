@@ -26,37 +26,49 @@ def decode_attention_ref(q, k, v, q_pos, k_pos, *, window: int = 0):
     return out.reshape(B, H, hd).astype(q.dtype)
 
 
-def paged_view(pool_k, pool_v, pool_pos, tables):
+def paged_view(pool_k, pool_v, pool_pos, tables, layer):
     """Gather a per-request contiguous (B, MB*bs) view of the paged pools
     (DESIGN §9) — the canonical block-table gather, shared by the paged
     decode oracle below and `models.layers.paged_view` (the production
     non-kernel path), so the two can never diverge.
+
+    pool_k/v: the stacked (L, NB, bs, KV, hd) pools, read at `layer` (an
+    int32 scalar) in place: the gather indexes the flattened stack at
+    layer*NB*bs + tables*bs + j, so no per-layer slice of the pool is
+    made. pool_pos: (NB, bs), shared by every layer.
 
     Logical block j of request b sits at view indices [j*bs, (j+1)*bs), so
     a token at absolute position p lands at view index p — the same index
     it has in a non-ring contiguous cache row, which keeps the paged and
     contiguous layouts bitwise comparable. Unallocated table entries (-1)
     read as empty slots (K/V = 0, pos = -1)."""
-    NB, bs = pool_k.shape[:2]
+    n, NB, bs = pool_k.shape[:3]
     B, MB = tables.shape
-    base = jnp.where(tables >= 0, tables * bs, NB * bs)        # (B, MB)
-    idx = (base[:, :, None] + jnp.arange(bs)[None, None, :]).reshape(B, MB * bs)
-    kf = pool_k.reshape((NB * bs,) + pool_k.shape[2:])
-    vf = pool_v.reshape((NB * bs,) + pool_v.shape[2:])
+    own = tables >= 0
+    offs = jnp.arange(bs)[None, None, :]
+
+    def flat_idx(base, oob):
+        return (jnp.where(own, base, oob)[:, :, None] + offs).reshape(
+            B, MB * bs)
+
+    kv_idx = flat_idx(layer * NB * bs + tables * bs, n * NB * bs)
+    pos_idx = flat_idx(tables * bs, NB * bs)
+    kf = pool_k.reshape((n * NB * bs,) + pool_k.shape[3:])
+    vf = pool_v.reshape((n * NB * bs,) + pool_v.shape[3:])
     pf = pool_pos.reshape(NB * bs)
-    k = kf.at[idx].get(mode="fill", fill_value=0)
-    v = vf.at[idx].get(mode="fill", fill_value=0)
-    kpos = pf.at[idx].get(mode="fill", fill_value=-1)
+    k = kf.at[kv_idx].get(mode="fill", fill_value=0)
+    v = vf.at[kv_idx].get(mode="fill", fill_value=0)
+    kpos = pf.at[pos_idx].get(mode="fill", fill_value=-1)
     return k, v, kpos
 
 
 def paged_decode_attention_ref(q, k_pool, v_pool, q_pos, kpos_pool, tables,
-                               *, window: int = 0):
+                               layer, *, window: int = 0):
     """Gather-then-attend oracle for the paged kernel (DESIGN §9).
 
-    q: (B, H, hd); k/v_pool: (NB, bs, KV, hd); q_pos: (B,);
-    kpos_pool: (NB, bs); tables: (B, MB), -1 = unallocated."""
-    k, v, kpos = paged_view(k_pool, v_pool, kpos_pool, tables)
+    q: (B, H, hd); k/v_pool: (L, NB, bs, KV, hd) read at `layer`;
+    q_pos: (B,); kpos_pool: (NB, bs); tables: (B, MB), -1 = unallocated."""
+    k, v, kpos = paged_view(k_pool, v_pool, kpos_pool, tables, layer)
     return decode_attention_ref(q, k, v, q_pos, kpos, window=window)
 
 

@@ -116,37 +116,43 @@ def _attn_block_train(p, x, positions, cfg, window, no_drop=False):
     return x + L.mlp(p["mlp"], h), 0.0
 
 
-def _attn_block_cached(p, x, positions, ck, cv, cpos, cfg, window):
-    h = L.rms_norm(x, p["ln1"], cfg.rms_eps)
-    a, ck, cv, cpos = L.self_attention_cached(
-        p["attn"], h, positions, ck, cv, cpos, cfg, window=window)
-    x = x + a
+def _serve_ffn(p, x, cfg):
     h = L.rms_norm(x, p["ln2"], cfg.rms_eps)
     if "moe" in p:
         # no_drop: serving must be chunking-invariant (see moe_apply docs)
         y, _ = L.moe_apply(p["moe"], h, cfg,
                            no_drop=cfg.moe.inference_no_drop)
-        x = x + y
-    else:
-        x = x + L.mlp(p["mlp"], h)
-    return x, ck, cv, cpos
+        return x + y
+    return x + L.mlp(p["mlp"], h)
 
 
-def _attn_block_paged(p, x, positions, ck, cv, cpos, tables, cfg, window):
-    """Paged twin of `_attn_block_cached`: K/V go through the block-table
-    indexed physical pools instead of a per-slot contiguous row (DESIGN §9)."""
+def _attn_block_cached(p, x, positions, ck, cv, cpos, cfg, window):
     h = L.rms_norm(x, p["ln1"], cfg.rms_eps)
-    a, ck, cv, cpos = L.self_attention_paged(
-        p["attn"], h, positions, ck, cv, cpos, tables, cfg, window=window)
-    x = x + a
-    h = L.rms_norm(x, p["ln2"], cfg.rms_eps)
-    if "moe" in p:
-        y, _ = L.moe_apply(p["moe"], h, cfg,
-                           no_drop=cfg.moe.inference_no_drop)
-        x = x + y
-    else:
-        x = x + L.mlp(p["mlp"], h)
-    return x, ck, cv, cpos
+    a, ck, cv, cpos = L.self_attention_cached(
+        p["attn"], h, positions, ck, cv, cpos, cfg, window=window)
+    return _serve_ffn(p, x + a, cfg), ck, cv, cpos
+
+
+def _attn_block_paged(p, x, positions, k_all, v_all, cpos, tables, slots,
+                      layer, cfg, window):
+    """Paged twin of `_attn_block_cached`: K/V go through the block-table
+    indexed stacked pools at `layer`, in place (DESIGN §9)."""
+    h = L.rms_norm(x, p["ln1"], cfg.rms_eps)
+    a, k_all, v_all = L.self_attention_paged(
+        p["attn"], h, positions, k_all, v_all, cpos, tables, slots, layer,
+        cfg, window=window)
+    return _serve_ffn(p, x + a, cfg), k_all, v_all
+
+
+def _attn_block_paged_slice(p, x, positions, ck, cv, cpos, tables, cfg,
+                            window):
+    """`_attn_block_paged` over one layer's (NB, bs, KV, hd) pool slice,
+    for the layer loops that still cut each layer's pool out of the stack
+    and write it back (hybrid, VLM, enc-dec; ROADMAP)."""
+    slots, cpos = L.paged_step_slots(positions, tables, cpos)
+    x, ck, cv = _attn_block_paged(p, x, positions, ck[None], cv[None], cpos,
+                                  tables, slots, 0, cfg, window)
+    return x, ck[0], cv[0], cpos
 
 
 def _cross_block(p, x, kv_k, kv_v, k_valid, cfg, gated):
@@ -600,33 +606,40 @@ def _attn_stack_cached(stacked, x, positions, cache, cfg, win, new_cache,
                        tables=None):
     """Layer loop for the cached (serving) path.
 
-    Uses fori_loop with dynamic_update_index on a loop-CARRIED cache rather
-    than scan xs/ys: scan rebuilds the stacked (L,B,S,KV,hd) cache as fresh
-    ys output (2-3x full-cache temp traffic per step); a while-loop carry
+    Uses fori_loop with the stacked caches as loop CARRY rather than scan
+    xs/ys: scan rebuilds the stacked (L,B,S,KV,hd) cache as fresh ys
+    output (2-3x full-cache temp traffic per step); a while-loop carry
     lets XLA update the (donated) buffer in place (§Perf iteration E).
-    With `tables` the per-layer k/v are the paged pools (DESIGN §9)."""
+    The contiguous cache is sliced per layer and written back. With
+    `tables` the k/v are the stacked paged pools (DESIGN §9), read and
+    written in place by layer index, so a step's pool traffic follows its
+    tokens, not the pool's size: slots and the pos map are made once,
+    before the loop, and no layer ever slices the pool."""
     cpos0 = cache["pos"]
-    L = cache["k"].shape[0]
+    n = cache["k"].shape[0]
+    if tables is not None:
+        slots, cpos0 = L.paged_step_slots(positions, tables, cpos0, n)
 
     def body(i, carry):
         h, k_all, v_all, cpos = carry
         lp = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             stacked)
+        if tables is not None:
+            h, k_all, v_all = _attn_block_paged(
+                lp, h, positions, k_all, v_all, cpos, tables, slots, i, cfg,
+                win)
+            return (h, k_all, v_all, cpos)
         ck = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
         cv = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
-        if tables is None:
-            h, ck, cv, cpos = _attn_block_cached(
-                lp, h, positions, ck, cv, cpos0, cfg, win)
-        else:
-            h, ck, cv, cpos = _attn_block_paged(
-                lp, h, positions, ck, cv, cpos0, tables, cfg, win)
+        h, ck, cv, cpos = _attn_block_cached(
+            lp, h, positions, ck, cv, cpos0, cfg, win)
         k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)
         v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, i, 0)
         return (h, k_all, v_all, cpos)
 
     x, k_n, v_n, cpos = jax.lax.fori_loop(
-        0, L, body, (x, cache["k"], cache["v"], cpos0))
+        0, n, body, (x, cache["k"], cache["v"], cpos0))
     new_cache["k"], new_cache["v"], new_cache["pos"] = k_n, v_n, cpos
     return x, new_cache
 
@@ -662,7 +675,7 @@ def _hybrid_cached(p, x, positions, cache, cfg, decode, tables=None):
                 h, ck, cv, cpos = _attn_block_cached(
                     one, h, positions, ck, cv, cpos0, cfg, win)
             else:
-                h, ck, cv, cpos = _attn_block_paged(
+                h, ck, cv, cpos = _attn_block_paged_slice(
                     one, h, positions, ck, cv, cpos0, tables, cfg, win)
             k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, j, 0)
             v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, j, 0)
@@ -715,7 +728,7 @@ def _vlm_cached(p, x, positions, cache, cfg, tables=None):
             h, ck, cv, cpos = _attn_block_cached(
                 lp, h, positions, ck, cv, cpos0, cfg, 0)
         else:
-            h, ck, cv, cpos = _attn_block_paged(
+            h, ck, cv, cpos = _attn_block_paged_slice(
                 lp, h, positions, ck, cv, cpos0, tables, cfg, 0)
         k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)
         v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, i, 0)
@@ -764,7 +777,7 @@ def _encdec_cached(p, x, positions, cache, cfg, tables=None):
             h, ck, cv, cpos = _attn_block_cached(
                 dec_p, h, positions, ck, cv, cpos0, cfg, 0)
         else:
-            h, ck, cv, cpos = _attn_block_paged(
+            h, ck, cv, cpos = _attn_block_paged_slice(
                 dec_p, h, positions, ck, cv, cpos0, tables, cfg, 0)
         h = _cross_block(cross_p, h, xk, xv, None, cfg, gated=False)
         k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)

@@ -183,48 +183,72 @@ def self_attention_cached(p, x, positions, cache_k, cache_v, cache_pos,
 
 
 def _pool_write(pool, flat_slots, val):
-    """Scatter per-token values into a flattened paged pool (DESIGN §9).
+    """Scatter per-token values into a paged pool viewed flat over its
+    slot axes (DESIGN §9).
 
-    pool: (NB, bs, ...); flat_slots: (B, T) flat indices into NB*bs, with
-    out-of-bounds (NB*bs) marking padding/unallocated tokens (dropped)."""
-    NB, bs = pool.shape[:2]
-    flat = pool.reshape((NB * bs,) + pool.shape[2:])
+    pool: (NB, bs) for the pos map, (L, NB, bs, KV, hd) for a stacked K/V
+    pool; val: (B, T) + trailing axes; flat_slots: (B, T) indices into the
+    pool's leading axes flattened, with any index past their end marking
+    padding/unallocated tokens (dropped)."""
+    rest = val.shape[flat_slots.ndim:]
+    flat = pool.reshape((-1,) + rest)
     return flat.at[flat_slots].set(val, mode="drop").reshape(pool.shape)
 
 
-def paged_view(pool_k, pool_v, pool_pos, tables):
-    """Gather a per-request contiguous (B, MB*bs) view of the paged pools
-    (DESIGN §9). Delegates to the canonical block-table gather in
-    `kernels.ref` so the production path and the kernel oracle can never
-    diverge on layout semantics."""
-    from repro.kernels.ref import paged_view as _paged_view
-    return _paged_view(pool_k, pool_v, pool_pos, tables)
+def paged_step_slots(positions, tables, pool_pos, num_layers: int = 1):
+    """Each new token's slot in the paged pools, and the pos map with this
+    step's positions written (DESIGN §9). Both are the same for every
+    layer, so a step makes them once, before its layer loop.
 
-
-def self_attention_paged(p, x, positions, pool_k, pool_v, pool_pos, tables,
-                         cfg: ModelConfig, *, window: int = 0):
-    """Self-attention through the physically paged KV pool (DESIGN §9).
-
-    x: (B, T, d) new tokens at absolute `positions` (B, T); pool_k/v:
-    (NB, bs, KV, hd) shared physical pools; pool_pos: (NB, bs) absolute
-    positions (-1 = empty); tables: (B, MB) per-request physical block ids
-    (-1 = unallocated). A token at position p is written to block
-    tables[b, p // bs], offset p % bs; padding (p < 0) and unallocated
-    blocks drop. Returns (out, new_pool_k, new_pool_v, new_pool_pos).
-    """
-    B, T, _ = x.shape
-    NB, bs = pool_k.shape[:2]
+    positions: (B, T) absolute, -1 = padding; tables: (B, MB) physical
+    block ids, -1 = unallocated; pool_pos: (NB, bs). A token at position
+    p goes to block tables[b, p // bs], offset p % bs: slot
+    tables[b, p // bs] * bs + p % bs of one layer's NB*bs slots, and
+    layer i's K/V sit i*NB*bs further on in the stacked pool. Padding and
+    unallocated blocks get num_layers*NB*bs, past the end of the stacked
+    pool at every layer's offset, so their writes drop.
+    Returns (slots (B, T), new pool_pos)."""
+    NB, bs = pool_pos.shape
     MB = tables.shape[1]
-    q, k, v = attention_qkv(p, x, cfg)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
     blk = jnp.clip(positions // bs, 0, MB - 1)
     phys = jnp.take_along_axis(tables, blk, axis=1)            # (B, T)
     ok = (positions >= 0) & (phys >= 0)
-    flat = jnp.where(ok, phys * bs + positions % bs, NB * bs)
-    pool_k = _pool_write(pool_k, flat, k)
-    pool_v = _pool_write(pool_v, flat, v)
-    pool_pos = _pool_write(pool_pos, flat, positions)
+    slots = jnp.where(ok, phys * bs + positions % bs, num_layers * NB * bs)
+    return slots, _pool_write(pool_pos, slots, positions)
+
+
+def paged_view(pool_k, pool_v, pool_pos, tables, layer):
+    """Gather a per-request contiguous (B, MB*bs) view of the stacked
+    paged pools at `layer` (DESIGN §9). Delegates to the canonical block-table gather in
+    `kernels.ref` so the production path and the kernel oracle can never
+    diverge on layout semantics."""
+    from repro.kernels.ref import paged_view as _paged_view
+    return _paged_view(pool_k, pool_v, pool_pos, tables, layer)
+
+
+def self_attention_paged(p, x, positions, k_all, v_all, pool_pos, tables,
+                         slots, layer, cfg: ModelConfig, *, window: int = 0):
+    """Self-attention of one layer through the stacked paged KV pools,
+    read and written in place (DESIGN §9).
+
+    x: (B, T, d) new tokens at absolute `positions` (B, T); k_all/v_all:
+    (L, NB, bs, KV, hd) stacked physical pools; pool_pos: (NB, bs) absolute
+    positions (-1 = empty), already holding this step's positions; tables:
+    (B, MB) per-request physical block ids (-1 = unallocated); slots and
+    pool_pos come from `paged_step_slots`; layer: this layer's index into
+    the stack (traced inside the layer loop). The new K/V scatter into the
+    stack at layer*NB*bs + slot, and attention reads the stack at `layer`:
+    no per-layer slice of the pool is ever made.
+    Returns (out, new_k_all, new_v_all).
+    """
+    B, T, _ = x.shape
+    NB, bs = pool_pos.shape
+    q, k, v = attention_qkv(p, x, cfg)
+    q = apply_rope(q, positions, cfg.rope_theta)
+    k = apply_rope(k, positions, cfg.rope_theta)
+    at = slots + layer * NB * bs
+    k_all = _pool_write(k_all, at, k)
+    v_all = _pool_write(v_all, at, v)
     # kernel routing under a serving mesh (DESIGN §12), derived from the
     # SAME rule that placed the pool (`kv_head_axes`): sharded on
     # kv-heads -> shard_map'd TP kernel; sharded on head_dim -> the
@@ -236,26 +260,26 @@ def self_attention_paged(p, x, positions, pool_k, pool_v, pool_pos, tables,
                                             serving_model_axis)
     kv_ax = hd_ax = None
     if serving_model_axis() > 1:
-        kv_ax, hd_ax = kv_head_axes(serving_mesh(), pool_k.shape[2],
-                                    pool_k.shape[3])
+        kv_ax, hd_ax = kv_head_axes(serving_mesh(), k_all.shape[-2],
+                                    k_all.shape[-1])
     if T == 1 and use_pallas() and hd_ax is None:
         # paged flash-decode Pallas kernel: the kv-block grid axis walks the
         # block table (kernels/decode_attention.py, DESIGN §9)
         from repro.kernels import ops
         if kv_ax is not None:
             out = ops.paged_decode_attention_tp(
-                q[:, 0], pool_k, pool_v, positions[:, 0], pool_pos, tables,
-                mesh=serving_mesh(), window=window)
+                q[:, 0], k_all, v_all, positions[:, 0], pool_pos, tables,
+                layer, mesh=serving_mesh(), window=window)
         else:
-            out = ops.paged_decode_attention(q[:, 0], pool_k, pool_v,
+            out = ops.paged_decode_attention(q[:, 0], k_all, v_all,
                                              positions[:, 0], pool_pos,
-                                             tables, window=window)
+                                             tables, layer, window=window)
         out = out.reshape(B, 1, -1)
     else:
-        kview, vview, kpos = paged_view(pool_k, pool_v, pool_pos, tables)
+        kview, vview, kpos = paged_view(k_all, v_all, pool_pos, tables, layer)
         out = attend(q, kview, vview, positions, kpos,
                      window=window, causal=True)
-    return out @ p["wo"], pool_k, pool_v, pool_pos
+    return out @ p["wo"], k_all, v_all
 
 
 def cross_attention(p, x, kv_k, kv_v, k_valid, cfg: ModelConfig, *,

@@ -31,15 +31,15 @@ def setup_model(arch):
 # paged flash-decode kernel vs gather-then-attend oracle
 
 
-def test_paged_kernel_matches_ref():
-    from repro.kernels import ops
-
+def _paged_case(n_layers):
+    """Stacked (n_layers, NB, bs, KV, hd) pools whose layers differ, with
+    non-contiguous, non-monotone physical blocks per request, unallocated
+    table slots, and stale positions in an unowned block."""
     rng = np.random.RandomState(0)
     B, H, KV, hd, NB, bs, MB = 3, 4, 2, 16, 10, 8, 4
     q = jnp.asarray(rng.randn(B, H, hd), jnp.float32)
-    kp = jnp.asarray(rng.randn(NB, bs, KV, hd), jnp.float32)
-    vp = jnp.asarray(rng.randn(NB, bs, KV, hd), jnp.float32)
-    # non-contiguous, non-monotone physical blocks per request
+    kp = jnp.asarray(rng.randn(n_layers, NB, bs, KV, hd), jnp.float32)
+    vp = jnp.asarray(rng.randn(n_layers, NB, bs, KV, hd), jnp.float32)
     owned = [[2, 5, 7], [1], [9, 0]]
     tables = np.full((B, MB), -1, np.int32)
     for b, tbl in enumerate(owned):
@@ -53,14 +53,52 @@ def test_paged_kernel_matches_ref():
                 if p <= int(q_pos[b]):
                     kpos[pb, o] = p
     kpos[3] = 2  # stale positions in an UNOWNED block must stay invisible
-    tables, kpos = jnp.asarray(tables), jnp.asarray(kpos)
+    return q, kp, vp, q_pos, jnp.asarray(kpos), jnp.asarray(tables)
+
+
+def test_paged_kernel_matches_ref():
+    from repro.kernels import ops
+
+    q, kp, vp, q_pos, kpos, tables = _paged_case(1)
     for window in (0, 6):
-        ref = ops.paged_decode_attention(q, kp, vp, q_pos, kpos, tables,
+        ref = ops.paged_decode_attention(q, kp, vp, q_pos, kpos, tables, 0,
                                          window=window, use_kernel=False)
-        ker = ops.paged_decode_attention(q, kp, vp, q_pos, kpos, tables,
+        ker = ops.paged_decode_attention(q, kp, vp, q_pos, kpos, tables, 0,
                                          window=window, use_kernel=True)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
                                    rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("route", ["kernel", "oracle", "paged_view"])
+def test_paged_reads_its_layer(route):
+    """Layer 2 of a 3-layer stacked pool reads layer 2: each route
+    matches the oracle on that layer cut out alone, and differs from
+    layer 0 (a reader stuck on layer 0 passes every L = 1 test)."""
+    from repro.kernels import ops, ref
+    from repro.models.layers import paged_view
+
+    q, kp, vp, q_pos, kpos, tables = _paged_case(3)
+
+    def read(layer):
+        if route == "paged_view":
+            return paged_view(kp, vp, kpos, tables, layer)
+        return (ops.paged_decode_attention(
+            q, kp, vp, q_pos, kpos, tables, layer,
+            use_kernel=route == "kernel"),)
+
+    if route == "paged_view":
+        want = ref.paged_view(kp[2:], vp[2:], kpos, tables, 0)
+    else:
+        want = (ref.paged_decode_attention_ref(
+            q, kp[2:], vp[2:], q_pos, kpos, tables, 0),)
+    got, first = read(jnp.int32(2)), read(0)
+    for g, w, f in zip(got, want, first):
+        if route == "kernel":
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=2e-5, atol=2e-5)
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert not np.allclose(np.asarray(got[0]), np.asarray(first[0]))
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +152,7 @@ def test_model_paged_equals_contiguous(arch):
         L = cache_c["k"].shape[0]
         for lay in range(L):
             kview, vview, kpos = paged_view(
-                cache_p["k"][lay], cache_p["v"][lay], cache_p["pos"], tables)
+                cache_p["k"], cache_p["v"], cache_p["pos"], tables, lay)
             for i, c in enumerate(cur):
                 np.testing.assert_array_equal(
                     np.asarray(cache_c["k"][lay, i, :c]),

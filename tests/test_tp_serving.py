@@ -156,21 +156,29 @@ SCRIPT = textwrap.dedent("""
     mesh = make_test_mesh((2, 2))
     B, H, KV, hd, NB, bs = 3, 4, 2, 32, 8, 16
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
-    kpool = jax.random.normal(ks[0], (NB, bs, KV, hd), jnp.float32)
-    vpool = jax.random.normal(ks[1], (NB, bs, KV, hd), jnp.float32)
+    # stacked pools of 3 layers, read at layer 2
+    kpool = jax.random.normal(ks[0], (3, NB, bs, KV, hd), jnp.float32)
+    vpool = jax.random.normal(ks[1], (3, NB, bs, KV, hd), jnp.float32)
     q = jax.random.normal(ks[2], (B, H, hd), jnp.float32)
     kpos = jnp.tile(jnp.arange(bs)[None], (NB, 1))
     tables = jnp.array([[0, 1, -1, -1], [2, 3, 4, -1], [5, -1, -1, -1]],
                        jnp.int32)
     qpos = jnp.array([20, 40, 10], jnp.int32)
-    tp = paged_decode_attention_tp(q, kpool, vpool, qpos, kpos, tables,
+    tp = paged_decode_attention_tp(q, kpool, vpool, qpos, kpos, tables, 2,
                                    mesh=mesh)
     single = paged_decode_attention_kernel(q, kpool, vpool, qpos, kpos,
-                                           tables, interpret=True)
-    ref = paged_decode_attention_ref(q, kpool, vpool, qpos, kpos, tables)
+                                           tables, 2, interpret=True)
+    ref = paged_decode_attention_ref(q, kpool, vpool, qpos, kpos, tables, 2)
+    # the oracle on layer 2 cut out alone: no layer index to get wrong
+    alone = paged_decode_attention_ref(q, kpool[2:], vpool[2:], qpos, kpos,
+                                       tables, 0)
+    layer0 = paged_decode_attention_ref(q, kpool, vpool, qpos, kpos, tables,
+                                        0)
     out["kernel"] = {
         "tp_bitwise_vs_single": bool(jnp.all(tp == single)),
         "tp_vs_ref_maxdiff": float(jnp.max(jnp.abs(tp - ref))),
+        "tp_vs_layer_alone_maxdiff": float(jnp.max(jnp.abs(tp - alone))),
+        "tp_vs_layer0_maxdiff": float(jnp.max(jnp.abs(tp - layer0))),
     }
 
     print("RESULT" + json.dumps(out))
@@ -240,3 +248,12 @@ def test_shard_map_paged_kernel_bitwise(tp_results):
     r = tp_results["kernel"]
     assert r["tp_bitwise_vs_single"]
     assert r["tp_vs_ref_maxdiff"] < 1e-5
+
+
+def test_shard_map_paged_kernel_reads_its_layer(tp_results):
+    """The TP kernel at layer 2 of a 3-layer stack reads layer 2: it
+    matches the oracle on that layer cut out alone, and not layer 0 (a
+    kernel that always read layer 0 would pass every L = 1 test)."""
+    r = tp_results["kernel"]
+    assert r["tp_vs_layer_alone_maxdiff"] < 1e-5
+    assert r["tp_vs_layer0_maxdiff"] > 1e-2

@@ -62,14 +62,14 @@ def _compiled_text(fn, *args) -> str:
 @pytest.mark.parametrize("batch", [8, 16])
 def test_paged_decode_kernel_compiles(one_chip, batch):
     bf, i32 = jnp.bfloat16, jnp.int32
-    pool = (NUM_BLOCKS, BLOCK, KV, HD)
+    pool = (2, NUM_BLOCKS, BLOCK, KV, HD)
     text = _compiled_text(
-        lambda q, k, v, qp, kp, t: paged_decode_attention_kernel(
-            q, k, v, qp, kp, t, interpret=False),
+        lambda q, k, v, qp, kp, t, lyr: paged_decode_attention_kernel(
+            q, k, v, qp, kp, t, lyr, interpret=False),
         _spec(one_chip, (batch, H, HD), bf), _spec(one_chip, pool, bf),
         _spec(one_chip, pool, bf), _spec(one_chip, (batch,), i32),
         _spec(one_chip, (NUM_BLOCKS, BLOCK), i32),
-        _spec(one_chip, (batch, MAX_BLOCKS), i32))
+        _spec(one_chip, (batch, MAX_BLOCKS), i32), _spec(one_chip, (), i32))
     assert "tpu_custom_call" in text
 
 
@@ -114,3 +114,44 @@ def test_paged_decode_step_compiles_at_full_width(one_chip, monkeypatch):
         _spec(one_chip, (batch,), i32),
         _spec(one_chip, (batch, MAX_BLOCKS), i32), cache)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,rows,tokens", [("decode", 8, 1),
+                                              ("prefill", 2, 16)])
+def test_paged_step_temp_under_one_layer_pool(one_chip, monkeypatch, kind,
+                                              rows, tokens):
+    """The paged steps address the stacked K/V pool in place (DESIGN §9):
+    mistral-nemo-12b at every published width, 10 layers, a 131072-token
+    pool and 8192-token tables, cache donated. A step that copied a
+    layer's pool out of the stack would need at least that pool, 268 MB,
+    of temporary memory; the in-place steps need under it, and the
+    pools stay aliased to the donated input."""
+    monkeypatch.setattr(layers, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("mistral-nemo-12b", "full"),
+                              num_layers=10)
+    model = build_model(cfg, dtype=jnp.bfloat16)
+    pool_tokens, block, context = 131072, 16, 8192
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = placed(model.init_shapes())
+    cache = placed(jax.eval_shape(lambda: model.init_paged_cache(
+        rows, pool_tokens // block, block)))
+    i32 = jnp.int32
+    tables = _spec(one_chip, (rows, context // block), i32)
+    if kind == "decode":
+        fn = model.decode_step_paged
+        toks = pos = _spec(one_chip, (rows,), i32)
+    else:
+        fn = model.prefill_paged
+        toks = pos = _spec(one_chip, (rows, tokens), i32)
+    compiled = jax.jit(fn, donate_argnums=(4,)).lower(
+        params, toks, pos, tables, cache).compile()
+    mem = compiled.memory_analysis()
+    layer_pool = cache["k"].size // cfg.num_layers * 2     # bf16 bytes
+    assert mem.temp_size_in_bytes < layer_pool, mem.temp_size_in_bytes
+    pools = (cache["k"].size + cache["v"].size) * 2
+    assert mem.alias_size_in_bytes >= pools
