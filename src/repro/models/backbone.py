@@ -144,17 +144,6 @@ def _attn_block_paged(p, x, positions, k_all, v_all, cpos, tables, slots,
     return _serve_ffn(p, x + a, cfg), k_all, v_all
 
 
-def _attn_block_paged_slice(p, x, positions, ck, cv, cpos, tables, cfg,
-                            window):
-    """`_attn_block_paged` over one layer's (NB, bs, KV, hd) pool slice,
-    for the layer loops that still cut each layer's pool out of the stack
-    and write it back (hybrid, VLM, enc-dec; ROADMAP)."""
-    slots, cpos = L.paged_step_slots(positions, tables, cpos)
-    x, ck, cv = _attn_block_paged(p, x, positions, ck[None], cv[None], cpos,
-                                  tables, slots, 0, cfg, window)
-    return x, ck[0], cv[0], cpos
-
-
 def _cross_block(p, x, kv_k, kv_v, k_valid, cfg, gated):
     h = L.rms_norm(x, p["ln1"], cfg.rms_eps)
     x = x + L.cross_attention(p["attn"], h, kv_k, kv_v, k_valid, cfg, gated=gated)
@@ -654,6 +643,9 @@ def _hybrid_cached(p, x, positions, cache, cfg, decode, tables=None):
     kinds = cfg.layer_kinds()
     win = cfg.rglru.window_size
     cpos0 = cache["pos"]
+    if tables is not None:
+        slots, cpos0 = L.paged_step_slots(positions, tables, cpos0,
+                                          cache["k"].shape[0])
     is_att = jnp.asarray(np.array([k == "attention" for k in kinds]))
     rec_of = jnp.asarray(np.cumsum([k == "recurrent" for k in kinds]) - 1)
     att_of = jnp.asarray(np.cumsum([k == "attention" for k in kinds]) - 1)
@@ -669,14 +661,15 @@ def _hybrid_cached(p, x, positions, cache, cfg, decode, tables=None):
             h, k_all, v_all, cpos, conv_all, rec_all = args
             j = att_of[i]
             one = take(p["att_layers"], j)
+            if tables is not None:
+                h, k_all, v_all = _attn_block_paged(
+                    one, h, positions, k_all, v_all, cpos, tables, slots, j,
+                    cfg, win)
+                return (h, k_all, v_all, cpos, conv_all, rec_all)
             ck = jax.lax.dynamic_index_in_dim(k_all, j, 0, keepdims=False)
             cv = jax.lax.dynamic_index_in_dim(v_all, j, 0, keepdims=False)
-            if tables is None:
-                h, ck, cv, cpos = _attn_block_cached(
-                    one, h, positions, ck, cv, cpos0, cfg, win)
-            else:
-                h, ck, cv, cpos = _attn_block_paged_slice(
-                    one, h, positions, ck, cv, cpos0, tables, cfg, win)
+            h, ck, cv, cpos = _attn_block_cached(
+                one, h, positions, ck, cv, cpos0, cfg, win)
             k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, j, 0)
             v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, j, 0)
             return (h, k_all, v_all, cpos, conv_all, rec_all)
@@ -715,23 +708,25 @@ def _vlm_cached(p, x, positions, cache, cfg, tables=None):
     n_cross = cfg.num_cross_layers
     per = cfg.num_layers // n_cross
     cpos0 = cache["pos"]
-    L = cfg.num_layers
+    n = cfg.num_layers
+    if tables is not None:
+        slots, cpos0 = L.paged_step_slots(positions, tables, cpos0, n)
 
     def body(i, carry):
         h, k_all, v_all, cpos = carry
         lp = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False),
             p["layers"])
-        ck = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
-        if tables is None:
+        if tables is not None:
+            h, k_all, v_all = _attn_block_paged(
+                lp, h, positions, k_all, v_all, cpos, tables, slots, i, cfg, 0)
+        else:
+            ck = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
             h, ck, cv, cpos = _attn_block_cached(
                 lp, h, positions, ck, cv, cpos0, cfg, 0)
-        else:
-            h, ck, cv, cpos = _attn_block_paged_slice(
-                lp, h, positions, ck, cv, cpos0, tables, cfg, 0)
-        k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)
-        v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, i, 0)
+            k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)
+            v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, i, 0)
 
         def with_cross(hh):
             j = i // per
@@ -749,7 +744,7 @@ def _vlm_cached(p, x, positions, cache, cfg, tables=None):
         return (h, k_all, v_all, cpos)
 
     x, k_n, v_n, cpos = jax.lax.fori_loop(
-        0, L, body, (x, cache["k"], cache["v"], cpos0))
+        0, n, body, (x, cache["k"], cache["v"], cpos0))
     cache = dict(cache)
     cache["k"], cache["v"], cache["pos"] = k_n, v_n, cpos
     return x, cache
@@ -760,6 +755,9 @@ def _encdec_cached(p, x, positions, cache, cfg, tables=None):
     `tables` decoder self-attention goes through the paged pools
     (DESIGN §9)."""
     cpos0 = cache["pos"]
+    if tables is not None:
+        slots, cpos0 = L.paged_step_slots(positions, tables, cpos0,
+                                          cfg.num_layers)
 
     def body(i, carry):
         h, k_all, v_all, cpos = carry
@@ -767,21 +765,22 @@ def _encdec_cached(p, x, positions, cache, cfg, tables=None):
             lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), t)
         dec_p = take(p["dec_layers"])
         cross_p = take(p["dec_cross"])
-        ck = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
-        cv = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
         xk = jax.lax.dynamic_index_in_dim(cache["cross_k"], i, 0,
                                           keepdims=False)
         xv = jax.lax.dynamic_index_in_dim(cache["cross_v"], i, 0,
                                           keepdims=False)
-        if tables is None:
+        if tables is not None:
+            h, k_all, v_all = _attn_block_paged(
+                dec_p, h, positions, k_all, v_all, cpos, tables, slots, i,
+                cfg, 0)
+        else:
+            ck = jax.lax.dynamic_index_in_dim(k_all, i, 0, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(v_all, i, 0, keepdims=False)
             h, ck, cv, cpos = _attn_block_cached(
                 dec_p, h, positions, ck, cv, cpos0, cfg, 0)
-        else:
-            h, ck, cv, cpos = _attn_block_paged_slice(
-                dec_p, h, positions, ck, cv, cpos0, tables, cfg, 0)
+            k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)
+            v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, i, 0)
         h = _cross_block(cross_p, h, xk, xv, None, cfg, gated=False)
-        k_all = jax.lax.dynamic_update_index_in_dim(k_all, ck, i, 0)
-        v_all = jax.lax.dynamic_update_index_in_dim(v_all, cv, i, 0)
         return (h, k_all, v_all, cpos)
 
     x, k_n, v_n, cpos = jax.lax.fori_loop(

@@ -195,7 +195,7 @@ def _pool_write(pool, flat_slots, val):
     return flat.at[flat_slots].set(val, mode="drop").reshape(pool.shape)
 
 
-def paged_step_slots(positions, tables, pool_pos, num_layers: int = 1):
+def paged_step_slots(positions, tables, pool_pos, num_layers: int):
     """Each new token's slot in the paged pools, and the pos map with this
     step's positions written (DESIGN §9). Both are the same for every
     layer, so a step makes them once, before its layer loop.

@@ -95,6 +95,20 @@ def cache_clear_row(cache: Dict[str, Any], i: int) -> Dict[str, Any]:
     return out
 
 
+def _i32(x) -> jnp.ndarray:
+    """Host ints to a device int32 array, compiling nothing: `jnp.array`
+    of a list compiles a conversion once per shape, and a shape first met
+    while serving would compile there."""
+    return jnp.asarray(np.asarray(x, np.int32))
+
+
+@jax.jit
+def _clear_pos_rows(pos, blocks):
+    """Clear the pos-pool rows of `blocks`; ids past the pool's end (the
+    padding of a fixed-length id vector) drop."""
+    return pos.at[blocks].set(-1, mode="drop")
+
+
 # per-slot state keys in paged mode: everything except the k/v/pos pools
 _POOL_KEYS = ("k", "v", "pos")
 
@@ -468,12 +482,21 @@ class Engine:
         """Clear the pos-pool rows of freed blocks so a future tenant never
         sees the previous request's stale positions (DESIGN §9)."""
         if self.paged and freed and "pos" in self.cache:
-            # eager: one small program per freed-block count
+            # one program for every freed-block count: the ids go in
+            # max_blocks at a time, padded past the pool's end, so no
+            # count compiles anew while serving (warmed in `warmup`)
             with _span(SPAN_RELEASE, blocks=len(freed)):
-                out = dict(self.cache)
-                out["pos"] = out["pos"].at[
-                    jnp.asarray(freed, jnp.int32)].set(-1)
-                self.cache = out
+                self._clear_blocks(freed)
+
+    def _clear_blocks(self, freed: List[int]):
+        pos = self.cache["pos"]
+        m = self.max_blocks
+        for i in range(0, len(freed), m):
+            ids = np.full((m,), pos.shape[0], np.int32)
+            part = freed[i:i + m]
+            ids[:len(part)] = part
+            pos = _clear_pos_rows(pos, jnp.asarray(ids))
+        self.cache = dict(self.cache, pos=pos)
 
     def _drain_released(self):
         """Clear pos rows of blocks the allocator evicted from the prefix
@@ -561,6 +584,11 @@ class Engine:
                 logits, self.cache = self._prefill_paged_jit(
                     self.params, tt, pos, tables, rows, self.cache, None)
                 jax.block_until_ready(logits)
+            if "pos" in self.cache:
+                # `_release_blocks`' one program; a block id past the
+                # pool's end clears nothing
+                self._clear_blocks([self.cache["pos"].shape[0]])
+                jax.block_until_ready(self.cache["pos"])
             return
         for b in self.buckets:
             sub = cache_take(self.cache, 0, b)
@@ -804,12 +832,12 @@ class Engine:
         last_logits: Dict[int, Any] = {}   # lane -> logits of its chunk
         for j, r, take in single:
             piece = r.prompt_tokens[:take]
-            tt = jnp.array([piece], jnp.int32)
-            pos = jnp.array([list(range(take))], jnp.int32)
+            tt = _i32([piece])
+            pos = _i32([list(range(take))])
             if self.paged:
                 logits, self.cache = self._prefill_paged_jit(
                     self.params, tt, pos, self._tables_for([r]),
-                    jnp.array([r.slot], jnp.int32), self.cache, r.extras)
+                    _i32([r.slot]), self.cache, r.extras)
             else:
                 slot = self.max_slots + j
                 sub = cache_take(self.cache, slot, 1)
@@ -824,13 +852,13 @@ class Engine:
                 # one paged graph per (rows, chunk) shape: the requests'
                 # pinned state rows + block tables (DESIGN §9)
                 reqs = [r for _, r, _ in entries]
-                rows = jnp.array([r.slot for r in reqs], jnp.int32)
-                tt = jnp.array(
+                rows = _i32([r.slot for r in reqs])
+                tt = _i32(
                     [r.prompt_tokens[r.prefill_pos:r.prefill_pos + take]
-                     for r in reqs], jnp.int32)
-                pos = jnp.array(
+                     for r in reqs])
+                pos = _i32(
                     [list(range(r.prefill_pos, r.prefill_pos + take))
-                     for r in reqs], jnp.int32)
+                     for r in reqs])
                 logits, self.cache = self._prefill_paged_jit(
                     self.params, tt, pos, self._tables_for(reqs), rows,
                     self.cache, None)
@@ -846,9 +874,9 @@ class Engine:
                 j, r, _ = entries[0]
                 slot = self.max_slots + j
                 piece = r.prompt_tokens[r.prefill_pos:r.prefill_pos + take]
-                tt = jnp.array([piece], jnp.int32)
-                pos = jnp.array([list(range(r.prefill_pos,
-                                            r.prefill_pos + take))], jnp.int32)
+                tt = _i32([piece])
+                pos = _i32([list(range(r.prefill_pos,
+                                       r.prefill_pos + take))])
                 sub = cache_take(self.cache, slot, 1)
                 logits, sub = self._prefill_jit(self.params, tt, pos, sub,
                                                 None)
@@ -857,14 +885,13 @@ class Engine:
                 rec.payload["probe"] = logits
                 last_logits[j] = logits[0]
                 continue
-            rows = jnp.array([self.max_slots + j for j, _, _ in entries],
-                             jnp.int32)
-            tt = jnp.array(
+            rows = _i32([self.max_slots + j for j, _, _ in entries])
+            tt = _i32(
                 [r.prompt_tokens[r.prefill_pos:r.prefill_pos + take]
-                 for _, r, _ in entries], jnp.int32)
-            pos = jnp.array(
+                 for _, r, _ in entries])
+            pos = _i32(
                 [list(range(r.prefill_pos, r.prefill_pos + take))
-                 for _, r, _ in entries], jnp.int32)
+                 for _, r, _ in entries])
             logits, self.cache = self._prefill_lanes_jit(
                 self.params, tt, pos, self.cache, rows)
             rec.dispatched = True
@@ -950,11 +977,10 @@ class Engine:
                 self._cow_blocks(self.blocks.cow_range(r.rid, start0,
                                                        len(toks)))
             tables = self._tables_for([r])
-            rows = jnp.array([slot], jnp.int32)
+            rows = _i32([slot])
             for start, piece in pieces:
-                tt = jnp.array([piece], jnp.int32)
-                pos = jnp.array([list(range(start, start + len(piece)))],
-                                jnp.int32)
+                tt = _i32([piece])
+                pos = _i32([list(range(start, start + len(piece)))])
                 ex = extras if start == 0 else None
                 logits, self.cache = self._prefill_paged_jit(
                     self.params, tt, pos, tables, rows, self.cache, ex)
@@ -964,9 +990,8 @@ class Engine:
         else:
             sub = cache_take(self.cache, slot, 1)
             for start, piece in pieces:
-                tt = jnp.array([piece], jnp.int32)
-                pos = jnp.array([list(range(start, start + len(piece)))],
-                                jnp.int32)
+                tt = _i32([piece])
+                pos = _i32([list(range(start, start + len(piece)))])
                 ex = extras if start == 0 else None
                 logits, sub = self._prefill_jit(self.params, tt, pos, sub, ex)
                 last_logits = logits[0, len(piece) - 1]
@@ -1024,7 +1049,7 @@ class Engine:
         bookkeeping — its generated tokens and TTFT stand, it re-enters
         through the swapped queue without re-prefill (DESIGN §11)."""
         pairs = self.blocks.swap_out(r.rid)
-        dev = jnp.asarray([d for d, _ in pairs], jnp.int32)
+        dev = _i32([d for d, _ in pairs])
         host = np.array([h for _, h in pairs], np.int32)
         for k, hp in self._host_pool.items():
             ax = 0 if k == "pos" else 1
@@ -1064,7 +1089,7 @@ class Engine:
         # BEFORE the restore, so they can never wipe the restored rows
         self._drain_released()
         host = np.array([h for h, _ in pairs], np.int32)
-        dev = jnp.asarray([d for _, d in pairs], jnp.int32)
+        dev = _i32([d for _, d in pairs])
         out = dict(self.cache)
         for k, hp in self._host_pool.items():
             if k == "pos":
@@ -1150,14 +1175,14 @@ class Engine:
         toks += [0] * (bucket - n)
         # the pending token sits at absolute position context_len - 1
         lens = [r.context_len - 1 for r in self.active] + [-1] * (bucket - n)
-        tt = jnp.array(toks, jnp.int32)
+        tt = _i32(toks)
         for i, dv in pend:
             tt = tt.at[i].set(dv)
-        ll = jnp.array(lens, jnp.int32)
+        ll = _i32(lens)
 
         if self.paged:
-            rows = jnp.array([r.slot for r in self.active]
-                             + [self.n_slots] * (bucket - n), jnp.int32)
+            rows = _i32([r.slot for r in self.active]
+                        + [self.n_slots] * (bucket - n))
             tables = self._tables_for(self.active, pad_to=bucket,
                                       kind="decode")
             logits, self.cache = self._decode_paged_jit(

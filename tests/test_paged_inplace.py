@@ -3,8 +3,9 @@
 back, and the result is bitwise the contiguous cache's.
 
 Structure is read from the jaxpr of `decode_step_paged` and
-`prefill_paged` at reduced widths, dense and MoE; values from a 3-layer
-model on the CPU.
+`prefill_paged` at reduced widths, for every family with a paged K/V
+pool: dense, MoE, hybrid, VLM and enc-dec; values from a 3-layer model on
+the CPU.
 """
 import dataclasses
 
@@ -20,6 +21,9 @@ from repro.models.model import build_model
 
 NB, BS, MB = 12, 8, 6          # pool blocks, block size, table width
 ROWS, CHUNK = 3, 16
+# depth per arch: enough that the stacked pool holds at least two layers
+# (recurrentgemma-9b's pattern puts one attention layer in three)
+DEPTH = {"recurrentgemma-9b": 6}
 
 
 def _model(arch, n_layers=3):
@@ -28,14 +32,16 @@ def _model(arch, n_layers=3):
     return cfg, build_model(cfg, dtype=jnp.float32)
 
 
-@pytest.mark.parametrize("arch", ["granite-3-8b", "qwen2-moe-a2.7b"])
+@pytest.mark.parametrize("arch", ["granite-3-8b", "qwen2-moe-a2.7b",
+                                  "recurrentgemma-9b", "llama-3.2-vision-90b",
+                                  "seamless-m4t-medium"])
 @pytest.mark.parametrize("step", ["decode", "decode_kernel", "prefill"])
 def test_step_never_slices_the_pool(monkeypatch, arch, step):
     """No dynamic_slice of the stacked pool, no dynamic_update_slice into
     it, and no value of one layer's pool shape (NB, bs, KV, hd), flat or
     not, anywhere in the step."""
     monkeypatch.setattr(layers, "use_pallas", lambda: step == "decode_kernel")
-    cfg, model = _model(arch)
+    cfg, model = _model(arch, DEPTH.get(arch, 3))
     params = model.init_shapes()
     cache = jax.eval_shape(lambda: model.init_paged_cache(ROWS, NB, BS))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731

@@ -229,6 +229,34 @@ def test_paged_eviction_zero_copies():
     assert sorted(eng._free_slots) == list(range(eng.n_slots))
 
 
+def test_release_is_one_warmed_program():
+    """Clearing the pos rows of any number of freed blocks, more than a
+    table's width too, runs the one program `warmup` compiled, and clears
+    exactly those rows."""
+    from repro.serving import engine as E
+    cfg, m, params = setup_model("granite-3-8b")
+    serve = ServeConfig(policy="static", b_max=2, max_new_tokens=4,
+                        kv_pool_tokens=512, block_size=16, paged_kv=True,
+                        chunked_prefill=True, chunk_budget_tokens=16)
+    eng = Engine(m, params, serve, max_context=64, buckets=(1, 2),
+                 prefill_chunk=8)
+    nb = eng.cache["pos"].shape[0]
+    assert nb > 2 * eng.max_blocks
+    eng.cache = dict(eng.cache, pos=jnp.arange(nb * 16, dtype=jnp.int32)
+                     .reshape(nb, 16))
+    want = np.asarray(eng.cache["pos"]).copy()
+    E._clear_pos_rows.clear_cache()
+    eng.warmup()
+    warmed = E._clear_pos_rows._cache_size()
+    assert warmed == 1
+    for freed in ([3], [0, 5, 9], list(range(10, 10 + 2 * eng.max_blocks
+                                               + 1))):
+        eng._release_blocks(freed)
+        want[freed] = -1
+        np.testing.assert_array_equal(np.asarray(eng.cache["pos"]), want)
+    assert E._clear_pos_rows._cache_size() == warmed
+
+
 def test_paged_multimodal_roundtrip():
     """Cross-KV state rides the pinned slot row; extras-carrying first
     chunks run through the paged single-row path."""

@@ -11,6 +11,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -71,6 +72,37 @@ def test_paged_decode_kernel_compiles(one_chip, batch):
         _spec(one_chip, (NUM_BLOCKS, BLOCK), i32),
         _spec(one_chip, (batch, MAX_BLOCKS), i32), _spec(one_chip, (), i32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("layers_in_stack", [1, 20])
+def test_tp_paged_decode_kernel_compiles_on_four_chips(topo, monkeypatch,
+                                                      layers_in_stack):
+    """The shard_map'd paged kernel for a (1, 4) serving mesh over the
+    described chips: stacked pools split on kv heads, the layer index and
+    tables replicated. Each chip runs the Mosaic kernel on its own heads;
+    no collective moves the pool."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    bf, i32, batch = jnp.bfloat16, jnp.int32, 8
+    pool = (layers_in_stack, NUM_BLOCKS, BLOCK, KV, HD)
+
+    def on(spec, shape, dtype):
+        return _spec(NamedSharding(mesh, spec), shape, dtype)
+
+    pool_spec = P(None, None, None, "model", None)
+    text = _compiled_text(
+        lambda q, k, v, qp, kp, t, lyr: ops.paged_decode_attention_tp(
+            q, k, v, qp, kp, t, lyr, mesh=mesh),
+        on(P(None, "model", None), (batch, H, HD), bf),
+        on(pool_spec, pool, bf), on(pool_spec, pool, bf),
+        on(P(None), (batch,), i32), on(P(None, None), (NUM_BLOCKS, BLOCK), i32),
+        on(P(None, None), (batch, MAX_BLOCKS), i32), on(P(), (), i32))
+    assert "tpu_custom_call" in text
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text, collective
 
 
 @pytest.mark.parametrize("batch,seq,window", [(8, SEQ, 0), (16, SEQ, 0),
