@@ -13,7 +13,8 @@ loop carried in _advance_prefill (4) and _decode_once (2): dispatch is now
 fence-free and exactly one blocking pair remains on the serving path — the
 retirement fence + bulk token readback in Engine._retire_step. warmup's
 blocks are pre-serving by construction; _swap_out's device_get IS the swap
-transfer. Total on-path syncs: 2 (was 6), whole file: 8 (was 12).
+transfer. Total on-path syncs: 2 (was 6), whole file: 8 (was 12). The
+`_i32` entry is no sync: its np.asarray only ever sees host lists.
 """
 from __future__ import annotations
 
@@ -35,6 +36,10 @@ ALLOWLIST: List[Allow] = [
     Allow("host-sync", ENGINE, "Engine._swap_out", 1,
           "device_get of evicted KV rows is the swap transfer itself "
           "(DESIGN SS11); it must complete before the rows are reused"),
+    Allow("host-sync", ENGINE, "_i32", 1,
+          "the operand is a host list of Python ints the scheduler built "
+          "(tokens, positions, slots, block ids); np.asarray converts host "
+          "data so that no per-shape conversion program compiles mid-serve"),
     Allow("counter-parity", ENGINE, "Engine.summary", 2,
           "copy_rows/copy_bytes count physical tensor row moves during "
           "defrag; the sim has no tensor storage, so no twin can exist"),

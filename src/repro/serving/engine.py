@@ -588,7 +588,6 @@ class Engine:
                 # `_release_blocks`' one program; a block id past the
                 # pool's end clears nothing
                 self._clear_blocks([self.cache["pos"].shape[0]])
-                jax.block_until_ready(self.cache["pos"])
             return
         for b in self.buckets:
             sub = cache_take(self.cache, 0, b)
