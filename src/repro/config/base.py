@@ -39,6 +39,59 @@ class MoEConfig:
     # chunking-invariant — CPU engine/tests); False = capacity_factor
     # dispatch (production TPU: bounds the (G,E,C) tensors; §Perf iter G)
     inference_no_drop: bool = True
+    # router: "softmax" = GShard top-k over softmax probabilities (the
+    # dense-dispatch path); "sigmoid" = DeepSeek-V3 noaux_tc: top-k of
+    # sigmoid scores plus a per-expert selection bias, gates the unbiased
+    # scores of the chosen experts normalised (norm_topk_prob) and times
+    # routed_scale, computed by the held-expert layer
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # expert parallelism (DESIGN §17): this chip holds experts
+    # [first_held_expert, first_held_expert + num_held_experts) of
+    # num_experts; 0 = all. The router keeps its full width.
+    num_held_experts: int = 0
+    first_held_expert: int = 0
+
+    @property
+    def held(self) -> int:
+        """Routed experts whose weights live on this chip."""
+        return self.num_held_experts or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V3): queries through a
+    q_lora_rank bottleneck, keys and values from one kv_lora_rank latent
+    per token plus one shared qk_rope_head_dim rotary key."""
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of the cached [c ; k_rope] per token per layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_lanes(self) -> int:
+        """latent_dim padded with zeros to a whole number of 128-lane
+        tiles: the width the caches and the absorbed queries carry. The
+        TPU lays a 576-wide array out in 640 lanes anyway, and a pool of
+        any other width is copied whole into that layout by every step."""
+        return -(-self.latent_dim // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V3 configures it (`rope_scaling`)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +131,11 @@ class ModelConfig:
     rms_eps: float = 1e-6
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    # latent attention (MLA) in place of GQA, and yarn rope on its rotary
+    # part; leading dense layers (width d_ff) before the MoE layers
+    mla: Optional[MLAConfig] = None
+    rope_scaling: Optional[YarnConfig] = None
+    first_k_dense: int = 0
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
     # enc-dec (audio backbone)
@@ -92,6 +150,8 @@ class ModelConfig:
     def resolved_head_dim(self) -> int:
         if self.head_dim:
             return self.head_dim
+        if self.mla is not None:
+            return self.mla.qk_nope_head_dim + self.mla.qk_rope_head_dim
         if not self.num_heads:
             return 0
         return self.d_model // self.num_heads
@@ -107,19 +167,16 @@ class ModelConfig:
         emb = V * d * (1 if self.tie_embeddings else 2)
         n = emb
         # Attention-bearing layers
-        att = (self.num_heads * h + 2 * self.num_kv_heads * h) * d \
-            + self.num_heads * h * d
+        att = self.attention_params()
         mlp = 3 * d * f  # SwiGLU
         if self.family in (ArchFamily.DENSE, ArchFamily.VLM):
             n += self.num_layers * (att + mlp + 2 * d)
             if self.family == ArchFamily.VLM and self.num_cross_layers:
                 n += self.num_cross_layers * (att + mlp + 2 * d)
         elif self.family == ArchFamily.MOE:
-            m = self.moe
-            routed = 3 * d * m.expert_ff_dim * m.num_experts
-            shared = 3 * d * m.shared_ff_dim if m.shared_ff_dim else 0
-            router = d * m.num_experts
-            n += self.num_layers * (att + routed + shared + router + 2 * d)
+            n += self.first_k_dense * (att + mlp + 2 * d) \
+                + self._moe_layers() * (att + self._moe_ffn_params(
+                    self.moe.held) + 2 * d)
         elif self.family == ArchFamily.SSM:
             s = self.ssm
             d_in = s.expand * d
@@ -150,15 +207,40 @@ class ModelConfig:
         if self.family != ArchFamily.MOE:
             return self.param_count()
         d = self.d_model
-        m = self.moe
+        att = self.attention_params()
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        dense = self.first_k_dense * (att + 3 * d * self.d_ff + 2 * d)
+        moe = self._moe_layers() * (att + self._moe_ffn_params(
+            self.moe.num_experts_per_tok) + 2 * d)
+        return emb + dense + moe + d
+
+    def attention_params(self) -> int:
+        """Weights of one self-attention block (GQA or MLA, norms in)."""
+        d, H = self.d_model, self.num_heads
+        if self.mla is not None:
+            m = self.mla
+            qk = m.qk_nope_head_dim + m.qk_rope_head_dim
+            return (d * m.q_lora_rank + m.q_lora_rank
+                    + m.q_lora_rank * H * qk
+                    + d * m.latent_dim + m.kv_lora_rank
+                    + m.kv_lora_rank * H * (m.qk_nope_head_dim
+                                            + m.v_head_dim)
+                    + H * m.v_head_dim * d)
         h = self.resolved_head_dim
-        att = (self.num_heads * h + 2 * self.num_kv_heads * h) * d \
-            + self.num_heads * h * d
-        routed_active = 3 * d * m.expert_ff_dim * m.num_experts_per_tok
+        return (H * h + 2 * self.num_kv_heads * h) * d + H * h * d
+
+    def _moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    def _moe_ffn_params(self, experts: int) -> int:
+        """One MoE layer's FFN: `experts` routed experts, the shared
+        expert, the router (and its selection bias under sigmoid)."""
+        d, m = self.d_model, self.moe
         shared = 3 * d * m.shared_ff_dim if m.shared_ff_dim else 0
         router = d * m.num_experts
-        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return emb + self.num_layers * (att + routed_active + shared + router + 2 * d) + d
+        if m.scoring == "sigmoid":
+            router += m.num_experts
+        return 3 * d * m.expert_ff_dim * experts + shared + router
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer kind sequence ('attention'|'recurrent'|'ssm'|'dense'|'cross')."""
@@ -178,6 +260,9 @@ class ModelConfig:
         h = self.resolved_head_dim
         if self.family == ArchFamily.SSM:
             return 0
+        if self.mla is not None:
+            # one [c ; k_rope] latent per token per layer, no V
+            return self.num_layers * self.mla.latent_dim * dtype_bytes
         n_att = sum(1 for k in self.layer_kinds() if k == "attention")
         layers = n_att if self.family == ArchFamily.HYBRID else self.num_layers
         if self.family == ArchFamily.ENCDEC:

@@ -10,6 +10,11 @@ One new query token per sequence against the KV cache, in two layouts:
   layer index and the table are scalar-prefetch operands so the BlockSpec
   index maps can chase them into the stacked pool.
 
+The paged kernel has a latent mode for MLA (DESIGN §17): the pool is
+(layers, num_blocks, block_size, C) of [c ; k_rope] latents, one C-wide
+key head shared by every absorbed query head, and the values are the first
+`latent_v` lanes of the same block, so no block is read twice.
+
 Both accumulate an online softmax in VMEM scratch. Masking is
 position-based (absolute positions per cache slot, -1 = empty), identical
 to the model's semantics — ring buffers / sliding windows / ragged paged
@@ -45,17 +50,33 @@ def _mask(kpos, qpos, window: int):
     return mask
 
 
-def _flash_accumulate(s, ns, q_ref, k_ref, v_ref, mask, o_ref, m_ref, l_ref,
-                      acc_ref):
+def _heads(k_ref, v_ref):
+    """Per kv head h: its (BS, hd) K and V of a (1, BS, KV, hd) tile."""
+    return lambda h: (k_ref[0, :, h, :].astype(jnp.float32),
+                      v_ref[0, :, h, :].astype(jnp.float32))
+
+
+def _latent(kv_ref, latent_v: int):
+    """The one latent head of a (1, BS, C) tile: K all C lanes, V its
+    first `latent_v`, from one read of the block."""
+    def kv(h):
+        k = kv_ref[0].astype(jnp.float32)                # (BS, C)
+        return k, k[:, :latent_v]
+    return kv
+
+
+def _flash_accumulate(s, ns, q_ref, kv_of, mask, o_ref, m_ref, l_ref,
+                      acc_ref, scale=None):
     """One kv tile of the online-softmax accumulate for every kv head,
     shared by the contiguous and paged decode kernels (which differ only
     in how the tile is addressed and masked).
 
-    q_ref: (1, KV, G, hd); k_ref/v_ref: (1, BS, KV, hd) — the tile holds
-    every kv head, so its minor two axes (KV, hd) are the array's own and
-    the TPU tiling rule holds at any head count; mask: (1, BS) bool.
-    Initializes the VMEM scratch on the first tile and writes o_ref on the
-    last."""
+    q_ref: (1, KV, G, hd); kv_of(h): head h's (BS, hd) K and (BS, dv) V
+    in fp32 (`_heads` of a (1, BS, KV, hd) tile that holds every kv head,
+    so its minor two axes (KV, hd) are the array's own and the TPU tiling
+    rule holds at any head count; or `_latent`); mask: (1, BS) bool;
+    scale: softmax scale, None = 1/sqrt(hd). Initializes the VMEM scratch
+    on the first tile and writes o_ref on the last."""
     @pl.when(s == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -63,13 +84,13 @@ def _flash_accumulate(s, ns, q_ref, k_ref, v_ref, mask, o_ref, m_ref, l_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     hd = q_ref.shape[-1]
-    for h in range(k_ref.shape[2]):
+    for h in range(q_ref.shape[1]):
         q = q_ref[0, h].astype(jnp.float32)              # (G, hd)
-        k = k_ref[0, :, h, :].astype(jnp.float32)        # (BS, hd)
-        v = v_ref[0, :, h, :].astype(jnp.float32)        # (BS, hd)
+        k, v = kv_of(h)                                  # (BS, hd), (BS, dv)
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) / math.sqrt(hd)  # (G, BS)
+            preferred_element_type=jnp.float32)          # (G, BS)
+        scores = scores / math.sqrt(hd) if scale is None else scores * scale
         scores = jnp.where(mask, scores, NEG_INF)
 
         m_prev = m_ref[h]                                # (G, 1)
@@ -106,8 +127,8 @@ def _kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
         # ragged last tile: slots past S hold whatever the edge block read
         col = s * bs + jax.lax.broadcasted_iota(jnp.int32, kpos.shape, 1)
         mask = mask & (col < seq_len)
-    _flash_accumulate(s, pl.num_programs(1), q_ref, k_ref, v_ref, mask,
-                      o_ref, m_ref, l_ref, acc_ref)
+    _flash_accumulate(s, pl.num_programs(1), q_ref, _heads(k_ref, v_ref),
+                      mask, o_ref, m_ref, l_ref, acc_ref)
 
 
 def decode_attention_kernel(q, k, v, q_pos, k_pos, *, window: int = 0,
@@ -147,21 +168,35 @@ def decode_attention_kernel(q, k, v, q_pos, k_pos, *, window: int = 0,
     return out.reshape(B, H, hd)
 
 
-def _paged_kernel(lyr_ref, tbl_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
-                  o_ref, m_ref, l_ref, acc_ref, *, window: int):
+def _paged_mask(tbl_ref, qpos_ref, kpos_ref, window: int):
+    """(table slot, (1, BS) mask) of this grid step of a paged kernel."""
     b = pl.program_id(0)
     s = pl.program_id(1)
     # unallocated table slots (-1) were clamped to physical block 0 by the
     # index map; a query limit of -1 masks the whole tile so block 0's real
     # tenant is invisible
     qlim = jnp.where(tbl_ref[b, s] >= 0, qpos_ref[b], -1)
-    mask = _mask(kpos_ref[0], qlim, window)              # (1, BS)
-    _flash_accumulate(s, pl.num_programs(1), q_ref, k_ref, v_ref, mask,
-                      o_ref, m_ref, l_ref, acc_ref)
+    return s, _mask(kpos_ref[0], qlim, window)
+
+
+def _paged_kernel(lyr_ref, tbl_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
+                  o_ref, m_ref, l_ref, acc_ref, *, window: int):
+    s, mask = _paged_mask(tbl_ref, qpos_ref, kpos_ref, window)
+    _flash_accumulate(s, pl.num_programs(1), q_ref, _heads(k_ref, v_ref),
+                      mask, o_ref, m_ref, l_ref, acc_ref)
+
+
+def _paged_latent_kernel(lyr_ref, tbl_ref, qpos_ref, q_ref, kv_ref, kpos_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, window: int,
+                         scale, latent_v: int):
+    s, mask = _paged_mask(tbl_ref, qpos_ref, kpos_ref, window)
+    _flash_accumulate(s, pl.num_programs(1), q_ref, _latent(kv_ref, latent_v),
+                      mask, o_ref, m_ref, l_ref, acc_ref, scale)
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
                                   tables, layer, *, window: int = 0,
+                                  scale=None, latent_v: int = 0,
                                   interpret: bool = True):
     """Paged flash decode (DESIGN §9).
 
@@ -177,7 +212,16 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
     (layer, tables[b, s]) to the block to stream straight out of the
     stacked pool, with no per-layer slice of it. Each step streams one
     block of every kv head; `kpos_pool` is viewed as (NB, 1, bs) so its
-    tile spans the array's own unit axis. Returns (B, H, hd)."""
+    tile spans the array's own unit axis. Returns (B, H, hd).
+
+    Latent mode (`latent_v` > 0): k_pool is the (L, NB, bs, C) latent
+    pool, v_pool None; q (B, H, C) holds the absorbed queries, every head
+    against the one latent key, and the values are each key's first
+    `latent_v` lanes. Returns (B, H, latent_v). `scale` is the softmax
+    scale (None = 1/sqrt(hd))."""
+    if latent_v:
+        return _paged_latent_call(q, k_pool, q_pos, kpos_pool, tables,
+                                  layer, window, scale, latent_v, interpret)
     B, H, hd = q.shape
     _, NB, bs, KV, _ = k_pool.shape
     MB = tables.shape[1]
@@ -211,3 +255,41 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
       q_pos.astype(jnp.int32), qr, k_pool, v_pool,
       kpos_pool.astype(jnp.int32).reshape(NB, 1, bs))
     return out.reshape(B, H, hd)
+
+
+def _paged_latent_call(q, kv_pool, q_pos, kpos_pool, tables, layer, window,
+                       scale, latent_v, interpret):
+    """The paged kernel's latent mode: the grid and table walk of
+    `paged_decode_attention_kernel`, one (bs, C) latent block a step."""
+    B, H, C = q.shape
+    _, NB, bs, _ = kv_pool.shape
+    MB = tables.shape[1]
+
+    def pool_map(b, s, lyr, t, qp):
+        return (lyr[0], jnp.maximum(t[b, s], 0), 0, 0)
+
+    def row_map(b, s, lyr, t, qp):
+        return (b, 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, MB),
+        in_specs=[
+            pl.BlockSpec((1, 1, H, C), row_map),                          # q
+            pl.BlockSpec((None, 1, bs, C), pool_map),                     # kv
+            pl.BlockSpec((1, 1, bs), lambda b, s, lyr, t, qp: (
+                jnp.maximum(t[b, s], 0), 0, 0)),                          # kpos
+        ],
+        out_specs=pl.BlockSpec((1, 1, H, latent_v), row_map),
+        scratch_shapes=_scratch(1, H, latent_v),
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_latent_kernel, window=window, scale=scale,
+                          latent_v=latent_v),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, H, latent_v), q.dtype),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tables.astype(jnp.int32),
+      q_pos.astype(jnp.int32), q.reshape(B, 1, H, C), kv_pool,
+      kpos_pool.astype(jnp.int32).reshape(NB, 1, bs))
+    return out.reshape(B, H, latent_v)

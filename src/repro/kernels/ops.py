@@ -36,17 +36,23 @@ def decode_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                                    block_s=block_s, interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("window", "use_kernel"))
+@functools.partial(jax.jit, static_argnames=("window", "use_kernel",
+                                             "scale", "latent_v"))
 def paged_decode_attention(q, k_pool, v_pool, q_pos, kpos_pool, tables, layer,
-                           *, window: int = 0, use_kernel: bool = True):
+                           *, window: int = 0, use_kernel: bool = True,
+                           scale=None, latent_v: int = 0):
     """Flash decode through the stacked paged KV pools + block tables at
-    one layer (DESIGN §9)."""
+    one layer (DESIGN §9); with `latent_v`, through the MLA latent pool
+    (v_pool None, DESIGN §17). `scale`: softmax scale, None =
+    1/sqrt(head dim)."""
     if not use_kernel:
         return ref.paged_decode_attention_ref(q, k_pool, v_pool, q_pos,
                                               kpos_pool, tables, layer,
-                                              window=window)
+                                              window=window, scale=scale,
+                                              latent_v=latent_v)
     return paged_decode_attention_kernel(q, k_pool, v_pool, q_pos, kpos_pool,
                                          tables, layer, window=window,
+                                         scale=scale, latent_v=latent_v,
                                          interpret=_interpret())
 
 

@@ -9,21 +9,23 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def decode_attention_ref(q, k, v, q_pos, k_pos, *, window: int = 0):
-    """q: (B, H, hd); k/v: (B, S, KV, hd); q_pos: (B,); k_pos: (B, S)."""
+def decode_attention_ref(q, k, v, q_pos, k_pos, *, window: int = 0,
+                         scale=None):
+    """q: (B, H, hd); k: (B, S, KV, hd); v: (B, S, KV, dv); q_pos: (B,);
+    k_pos: (B, S); scale: softmax scale, None = 1/sqrt(hd)."""
     B, H, hd = q.shape
     KV = k.shape[2]
     G = H // KV
     qh = q.reshape(B, KV, G, hd).astype(jnp.float32)
     scores = jnp.einsum("bkgd,bskd->bkgs", qh, k.astype(jnp.float32))
-    scores = scores / math.sqrt(hd)
+    scores = scores / math.sqrt(hd) if scale is None else scores * scale
     mask = (k_pos >= 0) & (k_pos <= q_pos[:, None])
     if window:
         mask = mask & (k_pos > q_pos[:, None] - window)
     scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", p, v.astype(jnp.float32))
-    return out.reshape(B, H, hd).astype(q.dtype)
+    return out.reshape(B, H, v.shape[-1]).astype(q.dtype)
 
 
 def paged_view(pool_k, pool_v, pool_pos, tables, layer):
@@ -35,7 +37,8 @@ def paged_view(pool_k, pool_v, pool_pos, tables, layer):
     pool_k/v: the stacked (L, NB, bs, KV, hd) pools, read at `layer` (an
     int32 scalar) in place: the gather indexes the flattened stack at
     layer*NB*bs + tables*bs + j, so no per-layer slice of the pool is
-    made. pool_pos: (NB, bs), shared by every layer.
+    made. pool_pos: (NB, bs), shared by every layer. A latent pool
+    (L, NB, bs, C) comes alone, with pool_v None (and None back).
 
     Logical block j of request b sits at view indices [j*bs, (j+1)*bs), so
     a token at absolute position p lands at view index p — the same index
@@ -53,23 +56,32 @@ def paged_view(pool_k, pool_v, pool_pos, tables, layer):
 
     kv_idx = flat_idx(layer * NB * bs + tables * bs, n * NB * bs)
     pos_idx = flat_idx(tables * bs, NB * bs)
+
     kf = pool_k.reshape((n * NB * bs,) + pool_k.shape[3:])
-    vf = pool_v.reshape((n * NB * bs,) + pool_v.shape[3:])
+    vf = None if pool_v is None \
+        else pool_v.reshape((n * NB * bs,) + pool_v.shape[3:])
     pf = pool_pos.reshape(NB * bs)
     k = kf.at[kv_idx].get(mode="fill", fill_value=0)
-    v = vf.at[kv_idx].get(mode="fill", fill_value=0)
+    v = None if vf is None else vf.at[kv_idx].get(mode="fill", fill_value=0)
     kpos = pf.at[pos_idx].get(mode="fill", fill_value=-1)
     return k, v, kpos
 
 
 def paged_decode_attention_ref(q, k_pool, v_pool, q_pos, kpos_pool, tables,
-                               layer, *, window: int = 0):
+                               layer, *, window: int = 0, scale=None,
+                               latent_v: int = 0):
     """Gather-then-attend oracle for the paged kernel (DESIGN §9).
 
     q: (B, H, hd); k/v_pool: (L, NB, bs, KV, hd) read at `layer`;
-    q_pos: (B,); kpos_pool: (NB, bs); tables: (B, MB), -1 = unallocated."""
+    q_pos: (B,); kpos_pool: (NB, bs); tables: (B, MB), -1 = unallocated.
+    Latent mode (`latent_v` > 0, DESIGN §17): k_pool is (L, NB, bs, C),
+    v_pool None, one C-wide key head shared by all H query heads and the
+    values its first `latent_v` lanes; returns (B, H, latent_v)."""
     k, v, kpos = paged_view(k_pool, v_pool, kpos_pool, tables, layer)
-    return decode_attention_ref(q, k, v, q_pos, kpos, window=window)
+    if latent_v:
+        k, v = k[:, :, None, :], k[:, :, None, :latent_v]
+    return decode_attention_ref(q, k, v, q_pos, kpos, window=window,
+                                scale=scale)
 
 
 def flash_attention_ref(q, k, v, q_pos, k_pos, *, window: int = 0,

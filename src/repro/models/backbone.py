@@ -59,23 +59,37 @@ def phys_cache_len(cfg: ModelConfig, max_context: int, chunk: int = 1) -> int:
 # per-layer blocks (single-layer params)
 
 
+def holds_experts(cfg: ModelConfig) -> bool:
+    """The MoE layers are the held-expert layer (DeepSeek-V3 routing,
+    DESIGN §17), not GShard dispatch: their serving caches count each
+    request's selections per held expert (`held`)."""
+    return cfg.moe is not None and cfg.moe.scoring == "sigmoid"
+
+
+def _self_attn_init(key, cfg: ModelConfig, dtype):
+    if cfg.mla is not None:
+        return L.init_mla(key, cfg, dtype)
+    return L.init_attention(key, cfg, dtype)
+
+
 def _dense_layer_init(key, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 2)
     return {
         "ln1": jnp.zeros((cfg.d_model,), dtype),
         "ln2": jnp.zeros((cfg.d_model,), dtype),
-        "attn": L.init_attention(ks[0], cfg, dtype),
+        "attn": _self_attn_init(ks[0], cfg, dtype),
         "mlp": L.init_mlp(ks[1], cfg.d_model, cfg.d_ff, cfg.num_layers, dtype),
     }
 
 
 def _moe_layer_init(key, cfg: ModelConfig, dtype):
     ks = jax.random.split(key, 2)
+    init_ffn = L.init_held_moe if holds_experts(cfg) else L.init_moe
     return {
         "ln1": jnp.zeros((cfg.d_model,), dtype),
         "ln2": jnp.zeros((cfg.d_model,), dtype),
-        "attn": L.init_attention(ks[0], cfg, dtype),
-        "moe": L.init_moe(ks[1], cfg, dtype),
+        "attn": _self_attn_init(ks[0], cfg, dtype),
+        "moe": init_ffn(ks[1], cfg, dtype),
     }
 
 
@@ -108,9 +122,16 @@ def _rec_layer_init(key, cfg: ModelConfig, dtype):
 
 def _attn_block_train(p, x, positions, cfg, window, no_drop=False):
     h = L.rms_norm(x, p["ln1"], cfg.rms_eps)
-    x = x + L.self_attention_train(p["attn"], h, positions, cfg, window=window)
+    if cfg.mla is not None:
+        x = x + L.mla_train(p["attn"], h, positions, cfg)
+    else:
+        x = x + L.self_attention_train(p["attn"], h, positions, cfg,
+                                       window=window)
     h = L.rms_norm(x, p["ln2"], cfg.rms_eps)
     if "moe" in p:
+        if holds_experts(cfg):
+            y, _ = L.held_moe_apply(p["moe"], h, cfg)
+            return x + y, 0.0
         y, aux = L.moe_apply(p["moe"], h, cfg, no_drop=no_drop)
         return x + y, aux
     return x + L.mlp(p["mlp"], h), 0.0
@@ -189,8 +210,14 @@ def init_params(key, cfg: ModelConfig, dtype=None) -> Params:
         p["layers"] = _stack_init(
             lambda k: _dense_layer_init(k, cfg, dt), ks[2], cfg.num_layers)
     elif fam == ArchFamily.MOE:
+        # leading dense layers (width d_ff), then the MoE layers
+        if cfg.first_k_dense:
+            p["dense_layers"] = _stack_init(
+                lambda k: _dense_layer_init(k, cfg, dt), ks[3],
+                cfg.first_k_dense)
         p["layers"] = _stack_init(
-            lambda k: _moe_layer_init(k, cfg, dt), ks[2], cfg.num_layers)
+            lambda k: _moe_layer_init(k, cfg, dt), ks[2],
+            cfg.num_layers - cfg.first_k_dense)
     elif fam == ArchFamily.SSM:
         p["layers"] = _stack_init(
             lambda k: _ssm_layer_init(k, cfg, dt), ks[2], cfg.num_layers)
@@ -259,7 +286,10 @@ def forward_train(p: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
             h, aux = carry
             h, a = _attn_block_train(lp, h, positions, cfg, win, no_drop)
             return h, aux + a
-        x, aux = _scan_layers(body, x, p["layers"], remat)
+        aux = 0.0
+        if "dense_layers" in p:
+            x, aux = _scan_layers(body, x, p["dense_layers"], remat)
+        x, aux = _scan_layers(body, x, p["layers"], remat, init_aux=aux)
 
     elif fam == ArchFamily.SSM:
         def body(carry, lp):
@@ -425,12 +455,19 @@ def init_cache(cfg: ModelConfig, batch: int, max_context: int,
     S = phys_cache_len(cfg, max_context, chunk)
     fam = cfg.family
     c: Cache = {}
-    if fam in (ArchFamily.DENSE, ArchFamily.MOE, ArchFamily.VLM,
-               ArchFamily.ENCDEC):
+    if cfg.mla is not None:
+        # one [c ; k_rope] latent per token per layer, no V (DESIGN §17)
+        c["k"] = jnp.zeros((cfg.num_layers, batch, S, cfg.mla.latent_lanes),
+                           dt)
+        c["pos"] = jnp.full((batch, S), -1, jnp.int32)
+    elif fam in (ArchFamily.DENSE, ArchFamily.MOE, ArchFamily.VLM,
+                 ArchFamily.ENCDEC):
         Ldec = cfg.num_layers
         c["k"] = jnp.zeros((Ldec, batch, S, KV, hd), dt)
         c["v"] = jnp.zeros((Ldec, batch, S, KV, hd), dt)
         c["pos"] = jnp.full((batch, S), -1, jnp.int32)
+    if holds_experts(cfg):
+        c["held"] = jnp.zeros((1, batch, cfg.moe.held), jnp.int32)
     if fam == ArchFamily.VLM:
         # cross KV filled at prefill from image embeddings
         c["cross_k"] = jnp.zeros(
@@ -469,18 +506,29 @@ def init_paged_cache(cfg: ModelConfig, n_slots: int, num_blocks: int,
     (num_blocks, block_size) absolute-position map (-1 = empty slot).
     Constant-size per-request state (SSM conv/ssm, RG-LRU conv/rec,
     cross-KV) stays per-slot with `n_slots` rows, pinned to a request for
-    its whole life so lane promotion / eviction never copy it."""
+    its whole life so lane promotion / eviction never copy it.
+
+    Under MLA (DESIGN §17) `k` is the latent pool (layers, num_blocks,
+    block_size, latent_lanes) of [c ; k_rope ; 0] and there is no `v`;
+    held-expert models add `held` (1, n_slots, held experts), each slot's
+    count of its tokens' selections per held expert."""
     dt = cfg_dtype(cfg, dtype)
     hd = cfg.resolved_head_dim
     KV = cfg.num_kv_heads
     fam = cfg.family
     c: Cache = {}
-    if fam in (ArchFamily.DENSE, ArchFamily.MOE, ArchFamily.VLM,
-               ArchFamily.ENCDEC):
+    if cfg.mla is not None:
+        c["k"] = jnp.zeros((cfg.num_layers, num_blocks, block_size,
+                            cfg.mla.latent_lanes), dt)
+        c["pos"] = jnp.full((num_blocks, block_size), -1, jnp.int32)
+    elif fam in (ArchFamily.DENSE, ArchFamily.MOE, ArchFamily.VLM,
+                 ArchFamily.ENCDEC):
         Ldec = cfg.num_layers
         c["k"] = jnp.zeros((Ldec, num_blocks, block_size, KV, hd), dt)
         c["v"] = jnp.zeros((Ldec, num_blocks, block_size, KV, hd), dt)
         c["pos"] = jnp.full((num_blocks, block_size), -1, jnp.int32)
+    if holds_experts(cfg):
+        c["held"] = jnp.zeros((1, n_slots, cfg.moe.held), jnp.int32)
     if fam == ArchFamily.VLM:
         c["cross_k"] = jnp.zeros(
             (cfg.num_cross_layers, n_slots, enc_len, KV, hd), dt)
@@ -544,7 +592,11 @@ def forward_cached(p: Params, tokens, positions, cache: Cache,
     win = window_of(cfg)
     new_cache = dict(cache)
 
-    if fam in (ArchFamily.DENSE, ArchFamily.MOE):
+    if cfg.mla is not None:
+        x, new_cache = _mla_stack_cached(p, x, positions, cache, cfg,
+                                         new_cache, tables=tables)
+
+    elif fam in (ArchFamily.DENSE, ArchFamily.MOE):
         x, new_cache = _attn_stack_cached(
             p["layers"], x, positions, cache, cfg, win, new_cache,
             tables=tables)
@@ -630,6 +682,61 @@ def _attn_stack_cached(stacked, x, positions, cache, cfg, win, new_cache,
     x, k_n, v_n, cpos = jax.lax.fori_loop(
         0, n, body, (x, cache["k"], cache["v"], cpos0))
     new_cache["k"], new_cache["v"], new_cache["pos"] = k_n, v_n, cpos
+    return x, new_cache
+
+
+def _mla_stack_cached(p, x, positions, cache, cfg, new_cache, tables=None):
+    """Layer loop of a latent-attention stack (DESIGN §17): the leading
+    dense layers, then the MoE layers, over one latent cache `k` — the
+    paged pool (L, NB, bs, C) read and written in place through `tables`,
+    or the contiguous (L, B, S, C) sliced per layer — with no V. Each
+    held-expert MoE layer adds its rows' selections per held expert (over
+    tokens at positions >= 0) to the `held` counts."""
+    cpos0 = cache["pos"]
+    valid = positions >= 0
+    if tables is not None:
+        slots, cpos0 = L.paged_step_slots(positions, tables, cpos0,
+                                          cache["k"].shape[0])
+    counts = jnp.zeros((x.shape[0], cfg.moe.held if holds_experts(cfg)
+                        else 0), jnp.int32)
+
+    def stack(stacked, first):
+        def body(i, carry):
+            h, kv_all, cpos, counts = carry
+            lp = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                       keepdims=False),
+                stacked)
+            j = i + first                        # the layer's pool index
+            hn = L.rms_norm(h, lp["ln1"], cfg.rms_eps)
+            if tables is not None:
+                a, kv_all = L.mla_paged(lp["attn"], hn, positions, kv_all,
+                                        cpos, tables, slots, j, cfg)
+            else:
+                ck = jax.lax.dynamic_index_in_dim(kv_all, j, 0,
+                                                  keepdims=False)
+                a, ck, cpos = L.mla_cached(lp["attn"], hn, positions, ck,
+                                           cpos0, cfg)
+                kv_all = jax.lax.dynamic_update_index_in_dim(kv_all, ck, j, 0)
+            h = h + a
+            hn = L.rms_norm(h, lp["ln2"], cfg.rms_eps)
+            if "moe" in lp:
+                y, c = L.held_moe_apply(lp["moe"], hn, cfg, valid)
+                counts = counts + c
+            else:
+                y = L.mlp(lp["mlp"], hn)
+            return (h + y, kv_all, cpos, counts)
+        return body
+
+    carry = (x, cache["k"], cpos0, counts)
+    if "dense_layers" in p:
+        carry = jax.lax.fori_loop(0, cfg.first_k_dense,
+                                  stack(p["dense_layers"], 0), carry)
+    carry = jax.lax.fori_loop(0, cfg.num_layers - cfg.first_k_dense,
+                              stack(p["layers"], cfg.first_k_dense), carry)
+    x, new_cache["k"], new_cache["pos"], counts = carry
+    if "held" in cache:
+        new_cache["held"] = cache["held"] + counts[None]
     return x, new_cache
 
 

@@ -82,7 +82,7 @@ def state_clear_row(cache: Dict[str, Any], i: int) -> Dict[str, Any]:
     (`pos` lives in the block pool there and is cleared when blocks free,
     DESIGN §9)."""
     out = dict(cache)
-    for k in ("conv", "rec", "ssm"):
+    for k in ("conv", "rec", "ssm", "held"):
         if k in cache:
             out[k] = cache[k].at[:, i].set(0)
     return out
@@ -138,6 +138,10 @@ class _StepRec:
         dataclasses.field(default_factory=list)
     #: lane -> packed chunk tokens: the on_prefill_interval feed
     lane_tokens: Optional[Dict[int, int]] = None
+    #: (request, life generation, state row) of every row this interval
+    #: ran, whose held-expert counts the "held" payload carries (DESIGN §17)
+    held_rows: List[Tuple[Request, int, int]] = \
+        dataclasses.field(default_factory=list)
     n_decode: int = 0
     dispatched: bool = False
 
@@ -338,6 +342,10 @@ class Engine:
         # device_s = the retirement fence wait, host_s = the remainder
         self.step_host_trace: List[float] = []
         self.step_device_trace: List[float] = []
+        # held-expert routing counter (DESIGN §17): the paged cache's
+        # per-slot `held` counts ride each retirement's readback
+        self.held = self.paged and "held" in self.cache
+        self.held_selections_total = 0
 
         self._decode_jit = self._mesh_call(jax.jit(self._decode_fn))
         self._prefill_jit = self._mesh_call(jax.jit(self._prefill_fn))
@@ -588,6 +596,11 @@ class Engine:
                 # `_release_blocks`' one program; a block id past the
                 # pool's end clears nothing
                 self._clear_blocks([self.cache["pos"].shape[0]])
+            if self.held:
+                # the readback's copy program, and the clear of a slot's
+                # counts when a request is pinned to it
+                self._held_snapshot()
+                self.cache = state_clear_row(self.cache, 0)
             return
         for b in self.buckets:
             sub = cache_take(self.cache, 0, b)
@@ -679,14 +692,17 @@ class Engine:
                 # one full chunk instead of livelocking
                 budget = self.prefill_chunk
             if self.prefilling:
-                with _span(SPAN_PREFILL, budget=budget, lanes_busy=min(
-                        self.n_lanes, len(self.prefilling))):
+                with _span(SPAN_PREFILL, **self._span_args(
+                        budget=budget, lanes_busy=min(
+                            self.n_lanes, len(self.prefilling)))):
                     self._advance_prefill(budget, rec)
             if self.active:
                 self._decode_once(rec)
         elif self.active:
             self._decode_once(rec)
         if rec.dispatched:
+            if rec.held_rows:
+                rec.payload["held"] = self._held_snapshot()
             self._inflight.append(rec)
         # retire down to the pipeline depth: the fence wait is the
         # interval's device time; everything else this call did is host
@@ -701,6 +717,19 @@ class Engine:
         # itself, not by the interval being scheduled (DESIGN §14)
         self.tel.on_interval(host_s, device_s)
         return True
+
+    def _held_snapshot(self):
+        """A copy of the per-slot held-expert counts, taken after the
+        interval's last dispatch: the next step donates the cache, and the
+        copy is read in the retirement's one readback (DESIGN §17)."""
+        return jnp.copy(self.cache["held"])
+
+    def _span_args(self, **kw) -> Dict[str, Any]:
+        """A prefill/decode span's args; with held experts, also the
+        held-expert selections retired so far (DESIGN §17)."""
+        if self.held:
+            kw["held_selections"] = self.held_selections_total
+        return kw
 
     def _admit(self, cap: int, rec: _StepRec) -> None:
         """Swap-in drain, then admission from `waiting`, up to `cap`
@@ -807,6 +836,9 @@ class Engine:
         for _, r, _ in plan:
             if r.prefill_start_time < 0:
                 r.prefill_start_time = self._now()
+        if self.held:
+            rec.held_rows.extend((r, self._gen.get(r.rid, 0), r.slot)
+                                 for _, r, _ in plan)
         if self.prefix:
             # COW guard (DESIGN §10): a shared block in this chunk's write
             # range gets a private copy first — structurally unreachable
@@ -996,6 +1028,8 @@ class Engine:
                 last_logits = logits[0, len(piece) - 1]
             self.cache = cache_put(self.cache, sub, slot)
         r.state = RequestState.RUNNING
+        if self.held:
+            rec.held_rows.append((r, self._gen.get(r.rid, 0), slot))
         # first token deferred to retirement (DESIGN §14); the synchronous
         # path never fed on_first_token here (no chunked service split),
         # so only the TTFT stamp rides in rec.firsts
@@ -1146,7 +1180,7 @@ class Engine:
         n = len(self.active)
         ge = [b for b in self.buckets if b >= n]
         bucket = min(ge) if ge else self.max_slots
-        with _span(SPAN_DECODE, rows=n, bucket=bucket):
+        with _span(SPAN_DECODE, **self._span_args(rows=n, bucket=bucket)):
             self._decode_batch(rec, n, bucket)
 
     def _decode_batch(self, rec: _StepRec, n: int, bucket: int):
@@ -1201,6 +1235,9 @@ class Engine:
         self.decode_steps += 1
         self.total_decoded += n
 
+        if self.held:
+            rec.held_rows.extend((r, self._gen.get(r.rid, 0), r.slot)
+                                 for r in self.active)
         sampled = rec.payload["dec"]
         finished = []
         grow_failed = []
@@ -1287,6 +1324,13 @@ class Engine:
             if idx < len(r.output_tokens) and r.output_tokens[idx] is None:
                 r.output_tokens[idx] = int(dec[k] if kind == "d"
                                            else first[k])
+        held = vals.get("held")
+        for r, gen, slot in rec.held_rows:
+            if self._gen.get(r.rid, 0) == gen:
+                counts = held[0, slot].tolist()
+                self.held_selections_total += \
+                    sum(counts) - sum(r.held_selections)
+                r.held_selections = counts
         if rec.lane_tokens is not None:
             self.tel.on_prefill_interval(rec.lane_tokens, self.n_lanes)
         for r, gen, feed, queue_s, t_ps in rec.firsts:

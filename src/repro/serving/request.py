@@ -49,6 +49,12 @@ class Request:
     swap_out_time: float = -1.0                  # pending swap-out timestamp
     swapped_s: float = 0.0                       # total time spent offloaded
     n_swaps: int = 0                             # completed swap round trips
+    # held-expert routing counter (DESIGN §17): of the selections of the
+    # tokens run since the request's state row was last assigned
+    # (admission or swap-in), summed over the MoE layers, how many landed
+    # on each expert this chip holds; read at retirement (paged engine,
+    # models whose MoE layers hold experts), empty otherwise
+    held_selections: List[int] = dataclasses.field(default_factory=list)
     # per-request goodput SLA verdict (DESIGN §15): stamped once — at
     # retirement in the engine, at finish/rejection in the sim — distinct
     # from the per-step `sla_attainment` window of d_sla_ms

@@ -15,7 +15,9 @@ ASSIGNED = {
     "granite-3-8b": ("dense", 40, 4096, 32, 8, 12800, 49155),
     "mistral-nemo-12b": ("dense", 40, 5120, 32, 8, 14336, 131072),
     "starcoder2-7b": ("dense", 32, 4608, 36, 4, 18432, 49152),
-    "kimi-k2-1t-a32b": ("moe", 61, 7168, 64, 8, 2048, 163840),
+    # Kimi-K2-Instruct: 64 kv heads as its config states (MLA), d_ff the
+    # dense first layer's width
+    "kimi-k2-1t-a32b": ("moe", 61, 7168, 64, 64, 18432, 163840),
     "mamba2-2.7b": ("ssm", 64, 2560, 0, 0, 0, 50280),
     "llama-3.2-vision-90b": ("vlm", 80, 8192, 64, 8, 28672, 128256),
 }
@@ -44,6 +46,35 @@ def test_moe_structure():
     assert q.moe.num_shared_experts == 4
     k = get_config("kimi-k2-1t-a32b")
     assert k.moe.num_experts == 384 and k.moe.num_experts_per_tok == 8
+    assert k.moe.num_shared_experts == 1 and k.moe.expert_ff_dim == 2048
+    assert k.moe.held == 384
+
+
+def test_kimi_k2_is_the_published_block():
+    """Kimi-K2-Instruct's config.json: MLA sizes, yarn, sigmoid/noaux
+    routing scaled by 2.827, a dense first layer of width 18432; `chip`
+    is that block cut to 5 layers and experts 0-7 of 384 held."""
+    k = get_config("kimi-k2-1t-a32b")
+    m = k.mla
+    assert (m.q_lora_rank, m.kv_lora_rank, m.qk_nope_head_dim,
+            m.qk_rope_head_dim, m.v_head_dim) == (1536, 512, 128, 64, 128)
+    assert k.resolved_head_dim == 192 and k.rope_theta == 50000
+    y = k.rope_scaling
+    assert (y.factor, y.original_max_position_embeddings, y.beta_fast,
+            y.beta_slow, y.mscale, y.mscale_all_dim) == (32, 4096, 1, 1, 1, 1)
+    assert (k.moe.scoring, k.moe.routed_scale) == ("sigmoid", 2.827)
+    assert k.first_k_dense == 1 and k.d_ff == 18432
+    assert not k.tie_embeddings
+    chip = get_config("kimi-k2-1t-a32b", "chip")
+    assert chip.num_layers == 5
+    assert (chip.moe.num_held_experts, chip.moe.first_held_expert,
+            chip.moe.num_experts, chip.moe.num_experts_per_tok) == \
+        (8, 0, 384, 8)
+    assert dataclasses.replace(chip, name=k.name, num_layers=61,
+                               moe=k.moe) == k
+    # one [c ; k_rope] latent of (512 + 64) x 2 B per layer, no V
+    assert chip.kv_bytes_per_token() == 5 * 576 * 2 == 5760
+    assert 4.8e9 < chip.param_count() < 4.9e9
 
 
 def test_param_scales():

@@ -176,3 +176,60 @@ def test_rmsnorm_matches_model_layer():
     np.testing.assert_allclose(
         np.asarray(ops.rmsnorm(x, w)), np.asarray(rms_norm(x, w)),
         rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention: the latent (MLA) mode and the GQA kernel's output
+
+
+def _paged_case(key, B, H, KV, hd, L, NB, bs, dtype, latent=False):
+    """A stacked paged pool at layer 1, two ragged rows through block
+    tables with -1 padding (KV/hd are the latent pool's lanes when
+    `latent`)."""
+    ks = jax.random.split(key, 3)
+    q = rand(ks[0], (B, H, hd), dtype)
+    pool = (L, NB, bs, hd) if latent else (L, NB, bs, KV, hd)
+    kp = rand(ks[1], pool, dtype)
+    vp = None if latent else rand(ks[2], pool, dtype)
+    tables = jnp.array([[3, 5, -1, -1], [0, 7, 2, -1]][:B], jnp.int32)
+    kpos = jnp.full((NB, bs), -1, jnp.int32)
+    for b, (blocks, n) in enumerate([([3, 5], 25), ([0, 7, 2], 40)][:B]):
+        for j, blk in enumerate(blocks):
+            p = np.arange(j * bs, (j + 1) * bs)
+            kpos = kpos.at[blk].set(jnp.where(p < n, p, -1))
+    qpos = jnp.array([24, 39][:B], jnp.int32)
+    return q, kp, vp, qpos, kpos, tables
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("H,C,dv,scale", [
+    (4, 128, 96, 0.3),          # reduced widths: 40 lanes padded to 128
+    (64, 640, 512, 0.1309),     # Kimi-K2: 576 lanes padded to 640, 64 heads
+])
+def test_latent_paged_decode_matches_ref(dtype, H, C, dv, scale):
+    """The paged kernel's latent mode (DESIGN §17) against
+    `ref.paged_decode_attention_ref`: one C-wide latent head shared by
+    every query head, V the first `dv` lanes of the same block."""
+    q, kp, _, qpos, kpos, tables = _paged_case(
+        jax.random.PRNGKey(3), 2, H, 1, C, 2, 8, 16, dtype, latent=True)
+    out = ops.paged_decode_attention(q, kp, None, qpos, kpos, tables, 1,
+                                     scale=scale, latent_v=dv)
+    want = ref.paged_decode_attention_ref(q, kp, None, qpos, kpos, tables, 1,
+                                          scale=scale, latent_v=dv)
+    assert out.shape == (2, H, dv)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), **tol(dtype))
+
+
+def test_granite_paged_decode_output_unchanged():
+    """The GQA paged kernel at granite-3-8b's head widths (32 q / 8 kv
+    heads of 128), interpret mode, bf16: its output is bitwise what the
+    kernel gave before it gained the latent mode and a softmax scale
+    (sha256 recorded from that kernel on this input)."""
+    import hashlib
+    case = _paged_case(jax.random.PRNGKey(11), 2, 32, 8, 128, 2, 8, 16,
+                       jnp.bfloat16)
+    out = np.asarray(ops.paged_decode_attention(*case, 1).astype(
+        jnp.float32))
+    assert hashlib.sha256(out.tobytes()).hexdigest() == \
+        "9b94f4e5dd42e71f6d08adbebc57870ab479c129ea4f161421442be3c03bd3a3"

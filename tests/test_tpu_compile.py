@@ -1,4 +1,5 @@
-"""The serving path's Pallas kernels compile for a TPU v5e at granite widths.
+"""The serving path's Pallas kernels compile for a TPU v5e at granite widths,
+and the latent mode and kimi-k2 `chip` steps at Kimi-K2-Instruct's.
 
 Compiled here, without a chip, for a described `v5e:2x2` topology: the TPU
 compiler refuses what interpret mode accepts (blocks off the (8, 128)
@@ -187,3 +188,90 @@ def test_paged_step_temp_under_one_layer_pool(one_chip, monkeypatch, kind,
     assert mem.temp_size_in_bytes < layer_pool, mem.temp_size_in_bytes
     pools = (cache["k"].size + cache["v"].size) * 2
     assert mem.alias_size_in_bytes >= pools
+
+
+# Kimi-K2-Instruct (MLA): one latent head of 576 lanes padded to 640, 64
+# absorbed query heads
+LAT, KL = 640, 512
+
+
+@pytest.mark.parametrize("batch", [8, 64])
+def test_latent_paged_decode_kernel_compiles(one_chip, batch):
+    """The paged kernel's latent mode (DESIGN §17): (5, NB, 16, 640)
+    latent pool, one (16, 640) block a grid step, V its first 512 lanes."""
+    bf, i32 = jnp.bfloat16, jnp.int32
+    pool = (5, NUM_BLOCKS, BLOCK, LAT)
+    text = _compiled_text(
+        lambda q, kv, qp, kp, t, lyr: paged_decode_attention_kernel(
+            q, kv, None, qp, kp, t, lyr, scale=0.13, latent_v=KL,
+            interpret=False),
+        _spec(one_chip, (batch, 64, LAT), bf), _spec(one_chip, pool, bf),
+        _spec(one_chip, (batch,), i32),
+        _spec(one_chip, (NUM_BLOCKS, BLOCK), i32),
+        _spec(one_chip, (batch, MAX_BLOCKS), i32), _spec(one_chip, (), i32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,rows,tokens", [("decode", 64, 0),
+                                              ("prefill", 2, 16)])
+def test_kimi_chip_steps_compile_and_keep_their_names(one_chip, monkeypatch,
+                                                      kind, rows, tokens):
+    """The engine's jitted paged steps of kimi-k2-1t-a32b `chip` (every
+    published width, 5 layers, 8 held experts) at the kimi-agent-backlog
+    cell's sizes: the 64-row decode bucket and a two-lane 16-token chunk,
+    16384-token tables, the 655,360-token latent pool, cache donated. The
+    programs carry the names the benchmark's trace reduction finds them by
+    (`jit__decode_paged_fn`, `jit__prefill_paged_fn`; the latent kernel
+    as `paged_decode_attention`), and weights, pool and the step's temps
+    fit one chip's 16 GiB."""
+    import re
+
+    from bench.harness import trace as T
+    from repro.config.base import ServeConfig
+    from repro.serving.engine import Engine
+    monkeypatch.setattr(layers, "use_pallas", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    model = build_model(get_config("kimi-k2-1t-a32b", "chip"),
+                        dtype=jnp.bfloat16)
+    # the engine's own pool is small: only the step programs are compiled,
+    # at the cell's shapes below
+    serve = ServeConfig(policy="static", b_max=rows, paged_kv=True,
+                        chunked_prefill=True, n_prefill_lanes=2,
+                        block_size=BLOCK, kv_pool_tokens=4 * BLOCK)
+    eng = Engine(model, None, serve, max_context=16384, buckets=(rows,),
+                 prefill_chunk=16)
+    eng._decode_paged_jit = jax.jit(eng._decode_paged_fn,
+                                    donate_argnums=(5,))
+    eng._prefill_paged_jit = jax.jit(eng._prefill_paged_fn,
+                                     donate_argnums=(5,))
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = placed(model.init_shapes())
+    cache = placed(jax.eval_shape(lambda: model.init_paged_cache(
+        eng.n_slots, 655360 // BLOCK, BLOCK)))
+    i32 = jnp.int32
+    shape = (rows, tokens) if tokens else (rows,)
+    args = (params, _spec(one_chip, shape, i32), _spec(one_chip, shape, i32),
+            _spec(one_chip, (rows, eng.max_blocks), i32),
+            _spec(one_chip, (rows,), i32), cache)
+    if kind == "decode":
+        compiled = eng._decode_paged_jit.lower(*args).compile()
+    else:
+        compiled = eng._prefill_paged_jit.lower(*args, None).compile()
+    text = compiled.as_text()
+    module = re.match(r"HloModule (\S+?),", text).group(1)
+    assert T.program_name(module) in T.PROGRAMS[kind]
+    calls = [T.op_name(line.strip()) for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # decode: the MoE layers' loop body holds the one latent kernel call
+    # site, the dense layer's loop another; prefill runs no kernel
+    want = T.KERNELS["paged_decode"][0]
+    assert calls == ([want] * 2 if kind == "decode" else []), calls
+    mem = compiled.memory_analysis()
+    print(kind, "args", mem.argument_size_in_bytes, "temp",
+          mem.temp_size_in_bytes, "alias", mem.alias_size_in_bytes)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < 16 * 2 ** 30
